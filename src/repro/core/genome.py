@@ -95,10 +95,10 @@ def active_mask(genome: Genome, spec: CGPSpec) -> jax.Array:
     Classic CGP "active node" computation (the paper's redundant encoding means
     most of the 400 nodes are usually inactive).  Because fan-ins always point
     backwards, a single reverse sweep over the node array suffices; implemented
-    as ``lax.scan`` so it stays jit/vmap friendly.
+    as ``lax.scan`` so it stays jit/vmap friendly.  Its device ops carry the
+    scope ``active_mask`` in their HLO metadata, whatever program calls it.
     """
     n_i, n_n = spec.n_i, spec.n_n
-    active0 = jnp.zeros((spec.n_wires,), dtype=bool).at[genome.outs].set(True)
     one_input = jnp.asarray(gates.ONE_INPUT)
 
     def step(active, k):
@@ -111,7 +111,10 @@ def active_mask(genome: Genome, spec: CGPSpec) -> jax.Array:
         active = active.at[node[1]].set(active[node[1]] | (is_act & uses_b))
         return active, None
 
-    active, _ = jax.lax.scan(step, active0, jnp.arange(n_n))
+    with jax.named_scope("active_mask"):
+        active0 = jnp.zeros((spec.n_wires,), dtype=bool) \
+            .at[genome.outs].set(True)
+        active, _ = jax.lax.scan(step, active0, jnp.arange(n_n))
     return active
 
 

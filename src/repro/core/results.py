@@ -48,6 +48,7 @@ fingerprinted grid, so concurrent creation by several pods is idempotent.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -57,6 +58,7 @@ import sys
 import zipfile
 from typing import Iterator, Sequence
 
+import jax
 import numpy as np
 
 from repro.checkpoint.store import atomic_save_npz, atomic_write_json
@@ -382,6 +384,7 @@ class SweepResultWriter:
                 continue
             return live
 
+    @functools.partial(jax.profiler.annotate_function, name="sweep.commit")
     def write_chunk(self, span: tuple[int, int],
                     rows: dict[str, np.ndarray]) -> str:
         """Atomically commit one chunk's rows as a shard.
@@ -390,7 +393,8 @@ class SweepResultWriter:
         ``sweep.plan_chunks``; ``rows`` must hold exactly the schema's fields
         with ``end - start`` rows each, including ``grid_rows`` (the
         grid-order index of each row — σ-grouped execution permutes the
-        grid, shards record the mapping).
+        grid, shards record the mapping).  The commit is the profiler span
+        ``sweep.commit``, on whichever thread runs it.
         """
         start, end = span
         n = end - start

@@ -656,6 +656,9 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
     with ``done_mask`` marking the covered grid rows.
     """
     from repro.core.search import CircuitRecord, problem_arrays
+    # the set-up, up to the first chunk, is the profiler span sweep.prepare
+    prepare = jax.profiler.TraceAnnotation("sweep.prepare")
+    prepare.__enter__()
 
     sweep = sweep or SweepConfig()
     mode = sweep.keep_history  # normalized by SweepConfig.__post_init__
@@ -813,6 +816,7 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
         store.save_checkpoint(sweep.checkpoint_dir, done, tree,
                               {"done": done, "fingerprint": fingerprint})
         store.cleanup(sweep.checkpoint_dir, keep=3)
+    prepare.__exit__(None, None, None)
 
     t0 = time.perf_counter()
     ran = chunks_run = 0
@@ -856,36 +860,58 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
                             jnp.asarray(thr[sel]))
                         migrator.stats["adopted"] += int(n_adopt)
 
-            if sweep.model_axis is not None:
-                evolve_call = _sharded_chunk_fn(ctx.get_mesh(),
-                                                sweep.model_axis, spec, ecfg)
-                state, hp, hm, hf = evolve_call(
-                    gold.nodes, gold.outs, jnp.asarray(thr[sel]), in_planes,
-                    gvals, gpower, jnp.asarray(keys[sel]))
-            elif dedup:
-                state, hp, hm, hf = _evolve_chunk_dedup(
-                    spec, ecfg, gold, jnp.asarray(thr[sel]), in_planes,
-                    gvals, gpower, jnp.asarray(keys[sel]), cache,
-                    (fingerprint, sigma) + sample_scope, state0=state0)
-            elif state0 is not None:
-                state, hp, hm, hf = evolve_chunk_seeded(
-                    spec, ecfg, state0, jnp.asarray(thr[sel]), in_planes,
-                    gvals, gpower)
-            else:
-                state, hp, hm, hf = evolve_chunk(
-                    spec, ecfg, gold, jnp.asarray(thr[sel]), in_planes,
-                    gvals, gpower, jnp.asarray(keys[sel]))
-            met, sterr, prel, feas, emean, estd = characterize_chunk(
-                spec, sigma, state.parent.nodes, state.parent.outs,
-                jnp.asarray(thr[sel]), in_planes, gvals, gpower,
-                sampled=sampled)
+            with jax.profiler.TraceAnnotation("sweep.dispatch"):
+                if sweep.model_axis is not None:
+                    evolve_call = _sharded_chunk_fn(
+                        ctx.get_mesh(), sweep.model_axis, spec, ecfg)
+                    state, hp, hm, hf = evolve_call(
+                        gold.nodes, gold.outs, jnp.asarray(thr[sel]),
+                        in_planes, gvals, gpower, jnp.asarray(keys[sel]))
+                elif dedup:
+                    state, hp, hm, hf = _evolve_chunk_dedup(
+                        spec, ecfg, gold, jnp.asarray(thr[sel]), in_planes,
+                        gvals, gpower, jnp.asarray(keys[sel]), cache,
+                        (fingerprint, sigma) + sample_scope, state0=state0)
+                elif state0 is not None:
+                    state, hp, hm, hf = evolve_chunk_seeded(
+                        spec, ecfg, state0, jnp.asarray(thr[sel]), in_planes,
+                        gvals, gpower)
+                else:
+                    state, hp, hm, hf = evolve_chunk(
+                        spec, ecfg, gold, jnp.asarray(thr[sel]), in_planes,
+                        gvals, gpower, jnp.asarray(keys[sel]))
+                met, sterr, prel, feas, emean, estd = characterize_chunk(
+                    spec, sigma, state.parent.nodes, state.parent.outs,
+                    jnp.asarray(thr[sel]), in_planes, gvals, gpower,
+                    sampled=sampled)
 
-            nodes_np = np.asarray(state.parent.nodes)[:n]
-            outs_np = np.asarray(state.parent.outs)[:n]
-            met_np = np.asarray(met)[:n].copy()
-            sterr_np = np.asarray(sterr)[:n].copy()
-            feas_np = np.asarray(feas)[:n].astype(np.uint8)
-            cert = np.zeros(n, np.uint8)
+            # histories stay in RAM ("full") or spill per chunk to a shard
+            keep_hist = mode == "full" or (writer is not None
+                                           and mode != "none")
+            with jax.profiler.TraceAnnotation("sweep.fetch"):
+                chunk_rows = {key: np.asarray(value)[:n] for key, value in (
+                    ("parent_nodes", state.parent.nodes),
+                    ("parent_outs", state.parent.outs),
+                    ("best_nodes", state.best.nodes),
+                    ("best_outs", state.best.outs),
+                    ("best_fit", state.best_fit),
+                    ("metrics", met),
+                    ("metrics_stderr", sterr),
+                    ("power_rel", prel),
+                    ("feasible", feas),
+                    ("error_mean", emean),
+                    ("error_std", estd))}
+                hist_rows = {key: np.asarray(value)[:n] for key, value in (
+                    ("hist_power_rel", hp), ("hist_fit", hf),
+                    ("hist_metrics", hm))} if keep_hist else {}
+            nodes_np = chunk_rows["parent_nodes"]
+            outs_np = chunk_rows["parent_outs"]
+            met_np = chunk_rows["metrics"] = chunk_rows["metrics"].copy()
+            sterr_np = chunk_rows["metrics_stderr"] = \
+                chunk_rows["metrics_stderr"].copy()
+            feas_np = chunk_rows["feasible"] = \
+                chunk_rows["feasible"].astype(np.uint8)
+            cert = chunk_rows["certified_mask"] = np.zeros(n, np.uint8)
             if not sampled:
                 cert[:] = 1  # the census is its own certificate (§10)
             elif certify_on:
@@ -893,7 +919,7 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
                 # tier: their shard rows become certified-exact measurements
                 cap = policy.chunk_budget(plan_pos[(start, end)], len(chunks))
                 for r in certify.select_escalations(
-                        feas_np, np.asarray(prel)[:n], cert, cap):
+                        feas_np, chunk_rows["power_rel"], cert, cap):
                     cmet = certify.certified_metrics(
                         nodes_np[r], outs_np[r], spec, cfg.kind, cfg.width,
                         sigma, dispatch_rows=policy.dispatch_rows)
@@ -904,35 +930,17 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
                     cert[r] = 1
                     n_escalated += 1
 
-            chunk_rows = {
-                "parent_nodes": nodes_np,
-                "parent_outs": outs_np,
-                "best_nodes": np.asarray(state.best.nodes)[:n],
-                "best_outs": np.asarray(state.best.outs)[:n],
-                "best_fit": np.asarray(state.best_fit)[:n],
-                "metrics": met_np,
-                "metrics_stderr": sterr_np,
-                "power_rel": np.asarray(prel)[:n],
-                "feasible": feas_np,
-                "certified_mask": cert,
-                "error_mean": np.asarray(emean)[:n],
-                "error_std": np.asarray(estd)[:n],
-            }
             for key, rows in chunk_rows.items():
                 bufs[key][orig] = rows
             if mode == "full":
-                bufs["hist_power_rel"][orig] = np.asarray(hp)[:n]
-                bufs["hist_fit"][orig] = np.asarray(hf)[:n]
-                bufs["hist_metrics"][orig] = np.asarray(hm)[:n]
+                for key, rows in hist_rows.items():
+                    bufs[key][orig] = rows
             if writer is not None:
                 chunk_rows["grid_rows"] = orig.astype(np.int32)
                 chunk_rows["thresholds"] = thr[orig]
-                if mode != "none":
-                    # histories spill per chunk and (in "summary" mode)
-                    # never touch a grid-sized host buffer
-                    chunk_rows["hist_power_rel"] = np.asarray(hp)[:n]
-                    chunk_rows["hist_fit"] = np.asarray(hf)[:n]
-                    chunk_rows["hist_metrics"] = np.asarray(hm)[:n]
+                # histories spill per chunk and (in "summary" mode) never
+                # touch a grid-sized host buffer
+                chunk_rows.update(hist_rows)
                 if committer is not None:
                     # chunk_rows and its arrays are freshly built per chunk
                     # and never mutated after this point — safe to hand to
@@ -970,28 +978,29 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
         if committer is not None:
             committer.close(raise_errors=False)
         raise
-    if committer is not None:
-        committer.close()
-    dt = time.perf_counter() - t0
+    with jax.profiler.TraceAnnotation("sweep.finish"):
+        if committer is not None:
+            committer.close()
+        dt = time.perf_counter() - t0
 
-    done_mask = np.zeros(n_runs, bool)
-    done_mask[perm[exec_done]] = True
-    records = []
-    for i in np.flatnonzero(done_mask):
-        con, seed = grid[i]
-        records.append(CircuitRecord(
-            genome_nodes=bufs["parent_nodes"][i],
-            genome_outs=bufs["parent_outs"][i],
-            metrics=bufs["metrics"][i],
-            power_rel=float(bufs["power_rel"][i]),
-            constraint=con.describe(),
-            seed=seed,
-            feasible=bool(bufs["feasible"][i]),
-            error_mean=float(bufs["error_mean"][i]),
-            error_std=float(bufs["error_std"][i]),
-            metrics_stderr=bufs["metrics_stderr"][i],
-            certified=bool(bufs["certified_mask"][i]),
-        ))
+        done_mask = np.zeros(n_runs, bool)
+        done_mask[perm[exec_done]] = True
+        records = []
+        for i in np.flatnonzero(done_mask):
+            con, seed = grid[i]
+            records.append(CircuitRecord(
+                genome_nodes=bufs["parent_nodes"][i],
+                genome_outs=bufs["parent_outs"][i],
+                metrics=bufs["metrics"][i],
+                power_rel=float(bufs["power_rel"][i]),
+                constraint=con.describe(),
+                seed=seed,
+                feasible=bool(bufs["feasible"][i]),
+                error_mean=float(bufs["error_mean"][i]),
+                error_std=float(bufs["error_std"][i]),
+                metrics_stderr=bufs["metrics_stderr"][i],
+                certified=bool(bufs["certified_mask"][i]),
+            ))
 
     return SweepResult(
         records=records,

@@ -19,8 +19,10 @@ and any pod can be re-launched to resume its slice.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 
+import jax
 import numpy as np
 
 from repro.core.fitness import ConstraintSpec
@@ -171,6 +173,13 @@ def main(argv=None):
                          "before failing (default: 120)")
     ap.add_argument("--serial", action="store_true",
                     help="reference serial loop instead of the batched engine")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record the sweep with the JAX profiler into DIR "
+                         "(an .xplane.pb for TensorBoard/XProf): device ops "
+                         "(the power model's scan under the scope "
+                         "'active_mask') and the sweep's host phases "
+                         "(spans sweep.prepare, sweep.dispatch, sweep.fetch, "
+                         "sweep.commit, sweep.finish)")
     args = ap.parse_args(argv)
     if args.pods > 1 and not args.results_dir:
         ap.error("--pods > 1 needs a shared --results-dir (the shard set "
@@ -214,8 +223,12 @@ def main(argv=None):
                             certify=args.certify,
                             certify_budget=args.certify_budget))
     constraints = [parse_constraint(c) for c in args.constraint]
+    profiled = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+                else contextlib.nullcontext())
     if args.serial:
-        records = run_sweep_serial(cfg, constraints, seeds=range(args.seeds))
+        with profiled:
+            records = run_sweep_serial(cfg, constraints,
+                                       seeds=range(args.seeds))
     else:
         mode = args.history or ("none" if args.no_history else "full")
         pod = args.pod_index
@@ -234,8 +247,9 @@ def main(argv=None):
                             async_commit=args.async_commit,
                             migrate_every=args.migrate_every,
                             migrate_timeout=args.migrate_timeout)
-        result = run_sweep_batched(cfg, constraints, seeds=range(args.seeds),
-                                   sweep=sweep)
+        with profiled:
+            result = run_sweep_batched(cfg, constraints,
+                                       seeds=range(args.seeds), sweep=sweep)
         records = result.records
         tag = f"pod {pod}/{args.pods}: " if args.pods > 1 else ""
         print(f"[evolve] {tag}{result.completed}/{result.n_runs} runs "

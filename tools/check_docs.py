@@ -68,7 +68,7 @@ REQUIRED_FLAGS = {
                                     "--certify", "--certify-budget",
                                     "--async-commit", "--migrate-every",
                                     "--migrate-timeout",
-                                    "--export-artifacts"],
+                                    "--export-artifacts", "--profile-dir"],
     ("-m", "repro.launch.serve"): ["--approx-lut", "--summary-out"],
     ("-m", "repro.launch.export"): ["--results-dir", "--out", "--top-k",
                                     "--require-certified", "--verify"],
