@@ -89,6 +89,12 @@ def validate_genome(genome: Genome, spec: CGPSpec) -> bool:
     return bool(ok)
 
 
+# Steps per loop iteration of the reverse sweep.  Once the update is dense
+# the loop's own overhead dominates a step; on a TPU v5e, 256 genomes of
+# 400 nodes take 0.57 ms a sweep at 2 against 0.86 at 1 and 0.69 at 4.
+_ACTIVE_MASK_UNROLL = 2
+
+
 def active_mask(genome: Genome, spec: CGPSpec) -> jax.Array:
     """Boolean (n_wires,) mask of wires reachable from the primary outputs.
 
@@ -97,24 +103,30 @@ def active_mask(genome: Genome, spec: CGPSpec) -> jax.Array:
     backwards, a single reverse sweep over the node array suffices; implemented
     as ``lax.scan`` so it stays jit/vmap friendly.  Its device ops carry the
     scope ``active_mask`` in their HLO metadata, whatever program calls it.
+
+    Each step ORs a one-hot of the node's fan-ins into the mask, compared
+    against a wire iota, instead of writing the two fan-in positions: under
+    ``vmap`` an indexed write becomes a scatter with one row index per
+    candidate, which the TPU runs row after row, while the compare-and-OR
+    over ``(batch, n_wires)`` is one fused elementwise op.
     """
     n_i, n_n = spec.n_i, spec.n_n
-    one_input = jnp.asarray(gates.ONE_INPUT)
-
-    def step(active, k):
-        # walk nodes from last to first
-        idx = n_n - 1 - k
-        node = genome.nodes[idx]
-        is_act = active[n_i + idx]
-        uses_b = one_input[node[2]] == 0
-        active = active.at[node[0]].set(active[node[0]] | is_act)
-        active = active.at[node[1]].set(active[node[1]] | (is_act & uses_b))
-        return active, None
+    wire = jnp.arange(spec.n_wires, dtype=jnp.int32)
 
     with jax.named_scope("active_mask"):
-        active0 = jnp.zeros((spec.n_wires,), dtype=bool) \
-            .at[genome.outs].set(True)
-        active, _ = jax.lax.scan(step, active0, jnp.arange(n_n))
+        in0, in1 = genome.nodes[:, 0], genome.nodes[:, 1]
+        uses_b = jnp.asarray(gates.ONE_INPUT)[genome.nodes[:, 2]] == 0
+
+        def step(active, k):
+            # walk nodes from last to first
+            idx = n_n - 1 - k
+            is_act = active[n_i + idx]
+            fanin = (wire == in0[idx]) | ((wire == in1[idx]) & uses_b[idx])
+            return active | (fanin & is_act), None
+
+        active0 = (wire[:, None] == genome.outs[None, :]).any(-1)
+        active, _ = jax.lax.scan(step, active0, jnp.arange(n_n),
+                                 unroll=_ACTIVE_MASK_UNROLL)
     return active
 
 

@@ -7,8 +7,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import gates, golden as G, metrics as M, simulate as S
-from repro.core.genome import (CGPSpec, Genome, active_mask, critical_path_ps,
-                               random_genome, validate_genome)
+from repro.core.genome import (CGPSpec, Genome, active_mask, active_mask_np,
+                               critical_path_ps, random_genome,
+                               validate_genome)
 from repro.core.mutate import mutate
 from repro.core.power import circuit_cost_from_probs
 
@@ -186,26 +187,70 @@ def test_gauss_envelope():
 
 # ----------------------------- genome ops -----------------------------------
 
+def _bruteforce_mask(nodes, outs, spec):
+    """Wires reachable from the outputs, by a depth-first walk."""
+    want = np.zeros(spec.n_wires, bool)
+    stack = list(outs)
+    while stack:
+        w = stack.pop()
+        if want[w]:
+            continue
+        want[w] = True
+        if w >= spec.n_i:
+            a, b, f = nodes[w - spec.n_i]
+            stack.append(int(a))
+            if not gates.ONE_INPUT[f]:
+                stack.append(int(b))
+    return want
+
+
 def test_active_mask_vs_bruteforce():
     spec = CGPSpec(n_i=6, n_o=4, n_n=40)
     for seed in range(5):
         g = random_genome(jax.random.PRNGKey(seed), spec)
         got = np.asarray(active_mask(g, spec))
-        nodes = np.asarray(g.nodes)
-        outs = np.asarray(g.outs)
-        want = np.zeros(spec.n_wires, bool)
-        stack = list(outs)
-        while stack:
-            w = stack.pop()
-            if want[w]:
-                continue
-            want[w] = True
-            if w >= spec.n_i:
-                a, b, f = nodes[w - spec.n_i]
-                stack.append(int(a))
-                if not gates.ONE_INPUT[f]:
-                    stack.append(int(b))
+        want = _bruteforce_mask(np.asarray(g.nodes), np.asarray(g.outs), spec)
         assert (got == want).all(), seed
+
+
+KINDS = ("random", "unary", "sparse", "mixed")
+
+
+def _paper_batch(kind, spec, n=256):
+    """``n`` genomes at ``spec``: random, rich in one-input gates, sparse
+    (outputs mostly on primary inputs), or a third of each."""
+    rng = np.random.default_rng(KINDS.index(kind))
+    keys = jax.random.split(jax.random.PRNGKey(int(rng.integers(2**31))), n)
+    g = jax.vmap(lambda k: random_genome(k, spec))(keys)
+    nodes, outs = np.array(g.nodes), np.array(g.outs)
+    third = n // 3
+    unary = {"unary": range(n), "mixed": range(third, 2 * third)}
+    sparse = {"sparse": range(n), "mixed": range(2 * third, n)}
+    for r in unary.get(kind, ()):  # four gates in five take one input
+        pick = rng.random(spec.n_n) < 0.8
+        nodes[r, pick, 2] = rng.choice(np.flatnonzero(gates.ONE_INPUT),
+                                       pick.sum())
+    for r in sparse.get(kind, ()):  # every other one keeps an output on a node
+        outs[r] = rng.integers(0, spec.n_i, spec.n_o)
+        if r % 2:
+            outs[r, rng.integers(spec.n_o)] = rng.choice(
+                [spec.n_wires - 1, rng.integers(spec.n_i, spec.n_wires)])
+    return nodes, outs
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_active_mask_vmapped_paper_shape(kind):
+    """``vmap(active_mask)`` over 256 genomes at the paper's shape equals the
+    host twin and the walk, gene for gene."""
+    spec = CGPSpec(n_i=16, n_o=16, n_n=400)
+    nodes, outs = _paper_batch(kind, spec)
+    got = np.asarray(jax.jit(jax.vmap(lambda g: active_mask(g, spec)))(
+        Genome(jnp.asarray(nodes), jnp.asarray(outs))))
+    assert got.shape == (256, spec.n_wires) and got.dtype == bool
+    np.testing.assert_array_equal(got, active_mask_np(nodes, outs, spec))
+    for r in range(len(nodes)):
+        np.testing.assert_array_equal(
+            got[r], _bruteforce_mask(nodes[r], outs[r], spec), err_msg=str(r))
 
 
 @given(st.integers(0, 2**31 - 1), st.floats(0.01, 0.5))

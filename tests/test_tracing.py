@@ -9,6 +9,7 @@ per-layer readers give the values they gave before the program had spans.
 How the benchmark reads scopes is tested with the benchmark
 (``bench/tests/test_scopes.py``).
 """
+import functools
 import gzip
 import os
 import re
@@ -58,17 +59,34 @@ def _read(red, name, counters):
 # What the program records (CPU)
 # --------------------------------------------------------------------------
 
-def test_active_mask_scope_in_compiled_hlo():
+@functools.lru_cache(maxsize=None)
+def _evolve_chunk_hlo() -> str:
+    """The compiled HLO text of one tiny sweep chunk."""
     gold, spec, in_planes, gvals, gpower = problem_arrays(CFG)
     thr = jnp.asarray(np.stack([c.thresholds() for c in CONSTRAINTS]))
     keys = jnp.stack([jax.random.PRNGKey(s) for s in SEEDS])
-    text = evolve_chunk.lower(spec, CFG.evolve, gold, thr, in_planes, gvals,
+    return evolve_chunk.lower(spec, CFG.evolve, gold, thr, in_planes, gvals,
                               gpower, keys).compile().as_text()
+
+
+def test_active_mask_scope_in_compiled_hlo():
+    text = _evolve_chunk_hlo()
     names = set(re.findall(r'op_name="([^"]*)"', text))
     # the golden parent's scan, and the generation step's vmapped one
     assert "jit(evolve_chunk)/active_mask/while" in names
     assert any(n.startswith("jit(evolve_chunk)/while/body/")
                and "/vmap(active_mask)/while/body/" in n for n in names)
+
+
+def test_no_scatter_under_active_mask():
+    """The active-mask scan updates its mask with no scatter: under ``vmap``
+    a scatter writes one candidate's row after another on the TPU."""
+    ops = re.findall(r' = .*?\s([a-z][\w-]*)\(.*op_name="([^"]*)"',
+                     _evolve_chunk_hlo())
+    scoped = [op for op, name in ops if "active_mask" in name.split("/")
+              or "vmap(active_mask)" in name.split("/")]
+    assert "while" in scoped
+    assert "scatter" not in scoped
 
 
 def _traced_sweep(tmp_path, **kw):
