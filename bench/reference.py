@@ -6,9 +6,10 @@ NumPy, what one run of the paper's (1+lambda) search must produce:
 * the golden circuit: the textbook array multiplier or ripple-carry adder,
   laid out gate by gate as a CGP genome and padded with BUF(0) nodes;
 * exhaustive evaluation of a genome over the whole 2^n_i input cube
-  (bit-parallel on int32 words, jitted: integers only), the error metrics of paper Eq. 1-7 in
-  percent, the uniform-input activity power model and the signed-error
-  moments;
+  (bit-parallel on int32 words, jitted: integers only), in blocks of the
+  cube that fit a fixed device budget (``BLOCK_BYTES``), the error metrics
+  of paper Eq. 1-7 in percent, the uniform-input activity power model and
+  the signed-error moments, combined from the blocks' partial results;
 * Eq. 8/9 fitness, (1+lambda) selection with neutral drift, and point
   mutation drawn from the per-run ``PRNGKey(seed)`` stream, so that a run can
   be replayed generation by generation.
@@ -19,9 +20,11 @@ comparison must refuse.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
 import ml_dtypes
@@ -123,9 +126,12 @@ def golden_genome(kind: str, width: int, n_n: int
     return np.array(nodes, np.int32), np.array(outs, np.int32)
 
 
-def golden_values(kind: str, width: int) -> np.ndarray:
-    """int64 exact result for every input x = a + 2^w * b of the cube."""
-    x = np.arange(1 << (2 * width), dtype=np.int64)
+def golden_values(kind: str, width: int, start: int = 0,
+                  stop: int | None = None) -> np.ndarray:
+    """int64 exact result for every input x = a + 2^w * b of the cube, or
+    of its inputs ``start`` to ``stop``."""
+    x = np.arange(start, 1 << (2 * width) if stop is None else stop,
+                  dtype=np.int64)
     a, b = x & ((1 << width) - 1), x >> width
     return a * b if kind == "mul" else a + b
 
@@ -154,32 +160,87 @@ class Problem:
         return 1 << self.n_i
 
 
-def _input_planes(n_i: int) -> np.ndarray:
-    """(n_i, 2^n_i / 32) int32: bit l of word k of row i is bit i of the
-    input index 32 k + l."""
-    if n_i < 5:
+#: device bytes that one simulation call may hold: its wire array and the
+#: unpacked output bits and values together (``call_bytes``).  1 GiB, a
+#: sixteenth of a v5e chip: a width-8 cube (2^16 rows) holds 128 genomes of
+#: 400 nodes in one call, and a width-12 cube (2^24 rows) of a 900-node
+#: genome is cut into four blocks of 2^22 rows.
+BLOCK_BYTES = 1 << 30
+#: host threads that reduce the blocks of a cube to partial results, while
+#: the device simulates the next (NumPy releases the interpreter lock in
+#: its loops); at most twice as many blocks wait at once
+HOST_THREADS = 8
+
+#: input rows 0-4 of every word: bit l of row i is bit i of l
+_LOW_PLANES = np.packbits(
+    ((np.arange(32) >> np.arange(5)[:, None]) & 1).astype(np.uint8),
+    axis=1, bitorder="little").view("<i4")[:, 0]
+
+
+def call_bytes(prob: Problem, genomes: int, words: int) -> dict[str, int]:
+    """Bytes of each int32 buffer of one simulation call of ``genomes``
+    genomes on ``words`` input words (32 rows of the cube each)."""
+    return {"wires": 4 * genomes * (prob.n_i + prob.n_n) * words,
+            "bits": 4 * genomes * prob.n_o * words * 32,
+            "values": 4 * genomes * words * 32}
+
+
+@dataclass(frozen=True)
+class BlockPlan:
+    """How B genomes are simulated: in groups of ``genomes`` (the batch
+    padded to ``padded``, a multiple of it), each on the cube in
+    ``blocks`` blocks of ``words`` input words."""
+    genomes: int
+    words: int
+    blocks: int
+    padded: int
+
+
+def block_plan(prob: Problem, batch: int, budget: int = BLOCK_BYTES
+               ) -> BlockPlan:
+    """The largest blocks whose buffers fit ``budget`` together.
+
+    The batch, padded to a power of two so that few shapes compile, is
+    halved into groups of genomes first, and only one genome's cube is cut
+    into blocks of words: a group changes no number, a word block the order
+    of the float sums over the cube.  So through width 8 every genome is
+    one block, whatever the batch."""
+    if prob.n_i < 5:
         raise ValueError("the reference packs at least 32 inputs per word")
-    x = np.arange(1 << n_i, dtype=np.int64)
-    bits = ((x[None, :] >> np.arange(n_i)[:, None]) & 1).astype(np.uint8)
-    return np.packbits(bits, axis=1, bitorder="little").view("<i4")
+    if prob.n_o > 31:
+        raise ValueError("an output word of more than 31 bits is no int32")
+    genomes, words = 1 << max(batch - 1, 0).bit_length(), prob.n_inputs // 32
+    while sum(call_bytes(prob, genomes, words).values()) > budget:
+        if genomes > 1:
+            genomes //= 2
+        elif words > 1:
+            words //= 2
+        else:
+            raise ValueError(f"no block of one word fits {budget} bytes")
+    return BlockPlan(genomes, words, prob.n_inputs // 32 // words,
+                     -(-batch // genomes) * genomes)
 
 
 @functools.lru_cache(maxsize=None)
-def _simulator(n_i: int, n_n: int, n_o: int):
-    """Jitted bit-parallel simulation of B genomes (integers only, so the
-    device it runs on cannot change its result)."""
+def _simulator(n_i: int, n_n: int, n_o: int, words: int):
+    """Jitted bit-parallel simulation of B genomes on the block of the cube
+    that starts at input word ``first`` (integers only, so the device it
+    runs on cannot change its result)."""
     import jax
     import jax.numpy as jnp
 
-    planes = jnp.asarray(_input_planes(n_i))
-    n_words = planes.shape[1]
+    low = jnp.asarray(_LOW_PLANES)[:, None]
 
     @jax.jit
-    def run(nodes, outs):
+    def run(nodes, outs, first):
         B = nodes.shape[0]
         rows = jnp.arange(B)
+        # bit l of word k of row i is bit i of the input 32 k + l
+        word = first + jnp.arange(words, dtype=jnp.int32)
+        high = -((word >> jnp.arange(n_i - 5, dtype=jnp.int32)[:, None]) & 1)
+        planes = jnp.concatenate([jnp.broadcast_to(low, (5, words)), high])
         tt = jnp.asarray(TRUTH, jnp.int32)[nodes[:, :, 2]]     # (B, n_n)
-        wires = jnp.zeros((B, n_i + n_n, n_words), jnp.int32)
+        wires = jnp.zeros((B, n_i + n_n, words), jnp.int32)
         wires = wires.at[:, :n_i].set(planes)
 
         def gate(wires, k):
@@ -195,26 +256,38 @@ def _simulator(n_i: int, n_n: int, n_o: int):
         out_planes = wires[rows[:, None], outs]                # (B, n_o, W)
         bits = (out_planes[..., None] >> jnp.arange(32)) & 1
         values = (bits << jnp.arange(n_o)[None, :, None, None]).sum(axis=1)
-        return ones, values.reshape(B, n_words * 32)
+        return ones, values.reshape(B, words * 32)
 
     return run
 
 
-def simulate(prob: Problem, nodes: np.ndarray, outs: np.ndarray
-             ) -> tuple[np.ndarray, np.ndarray]:
-    """Simulate B genomes on the whole cube.
+def simulate_blocks(prob: Problem, nodes: np.ndarray, outs: np.ndarray,
+                    plan: BlockPlan):
+    """Simulate B genomes on the whole cube, block by block, as ``plan``
+    (``block_plan`` of B) cuts it.
 
-    Returns (ones (B, n_n): how many inputs set each gate's output,
-    values (B, 2^n_i): the output word of every input), both int64.  The
-    batch is padded to a power of two, so that few batch sizes compile."""
+    Yields (genomes: the slice of the batch, block: its index on the cube,
+    ones (g, n_n) int64: how many inputs of the block set each gate's
+    output, values (g, rows) int32: the output word of each input of the
+    block, the inputs ``block * rows`` on)."""
     nodes, outs = np.asarray(nodes, np.int32), np.asarray(outs, np.int32)
     B = len(nodes)
-    pad = (1 << max(B - 1, 0).bit_length()) - B
-    ones, values = _simulator(prob.n_i, prob.n_n, prob.n_o)(
-        np.concatenate([nodes, np.repeat(nodes[:1], pad, 0)]),
-        np.concatenate([outs, np.repeat(outs[:1], pad, 0)]))
-    return (np.asarray(ones, np.int64)[:B],
-            np.asarray(values, np.int64)[:B])
+    pad = plan.padded - B
+    nodes = np.concatenate([nodes, np.repeat(nodes[:1], pad, 0)])
+    outs = np.concatenate([outs, np.repeat(outs[:1], pad, 0)])
+    run = _simulator(prob.n_i, prob.n_n, prob.n_o, plan.words)
+    for block in range(plan.blocks):
+        for g in range(0, plan.padded, plan.genomes):
+            group = slice(g, g + plan.genomes)
+            real = slice(g, min(g + plan.genomes, B))
+            ones, values = run(nodes[group], outs[group],
+                               np.int32(block * plan.words))
+            k = real.stop - real.start
+            # copied here, so that this thread frees the host buffer of the
+            # device's values: left to the host threads, the buffers of a
+            # batch of 32 width-12 genomes filled a v5e host's 40 GiB
+            yield (real, block, np.asarray(ones, np.int64)[:k],
+                   np.asarray(values)[:k].copy())
 
 
 def active_gates(prob: Problem, nodes: np.ndarray, outs: np.ndarray
@@ -260,40 +333,103 @@ class Evaluation:
     #: complement (p and 1 - p switch alike).  Genomes alike here have the
     #: same power to the last bit in any program, whatever their wiring.
     power_key: list | None = None
+    ones: np.ndarray | None = None  # (B, n_n) int64: inputs setting a gate
+    hist: np.ndarray | None = None  # (B, 2 GAUSS_SIDE + 2) int64: of d != 0
 
 
-def evaluate(prob: Problem, nodes: np.ndarray, outs: np.ndarray,
-             dtype=np.float64) -> Evaluation:
-    """Metrics (paper Eq. 1-7, magnitudes in percent of 2^n_o), power and
-    signed-error moments of B genomes over the exhaustive cube."""
-    nodes = np.asarray(nodes)
-    ones, cand = simulate(prob, nodes, outs)
-    gold = golden_values(prob.kind, prob.width)[None, :]
-    n = prob.n_inputs
+def _block_partials(gold: np.ndarray, cand: np.ndarray, edges: np.ndarray,
+                    dtype) -> tuple:
+    """What one block of the cube gives of g genomes: the inputs in error,
+    the largest |d|, whether no input of golden 0 reads non-zero, and the
+    Gauss histogram (integers), and the sums of |d|, d, |d| / max(golden, 1)
+    and M2, the squared deviations about the block's own mean (``dtype``).
+    """
     f = lambda x: np.asarray(x, dtype)
     diff = gold - cand
     ad = np.abs(diff)
     nz = diff != 0
-    out_pct = f(100.0) / f(1 << prob.n_o)
-
-    edges, mass = gauss_bins(prob.gauss_sigma)
     idx = np.searchsorted(edges, diff, side="right")
-    n_bins = len(edges) + 1
-    hist = np.stack([np.bincount(i[m], minlength=n_bins)
+    hist = np.stack([np.bincount(i[m], minlength=len(edges) + 1)
                      for i, m in zip(idx, nz)])
-    gauss_ok = np.all(hist <= f(mass) * f(n), axis=1)
+    s = _sum(diff, dtype)
+    mean = s / f(diff.shape[1])
+    sums = (_sum(ad, dtype), s, _sum(f(ad) / f(np.maximum(gold, 1)), dtype),
+            _sum((f(diff) - mean[:, None]) ** 2, dtype))
+    return (nz.sum(axis=1), ad.max(axis=1),
+            ~np.any((gold == 0) & (cand != 0), axis=1), hist, sums)
 
-    mae = _sum(ad, dtype) / f(n)
-    rel = f(ad) / f(np.maximum(gold, 1))
+
+def evaluate(prob: Problem, nodes: np.ndarray, outs: np.ndarray,
+             dtype=np.float64, budget: int = BLOCK_BYTES) -> Evaluation:
+    """Metrics (paper Eq. 1-7, magnitudes in percent of 2^n_o), power and
+    signed-error moments of B genomes over the exhaustive cube.
+
+    The cube is evaluated in the blocks of ``block_plan``: the device
+    simulates a block while ``HOST_THREADS`` threads reduce earlier ones to
+    partial results (``_block_partials``), and no (B, 2^n_i) array is kept.
+    Counts, the largest error and the gates' signal counts are integers.
+    The float sums are held in ``dtype`` in each block's partial and in
+    their combination.  The variance combines each block's mean and M2 by
+    the parallel form of Chan, Golub and LeVeque,
+    M2 = sum M2_b + sum rows_b (mean_b - mean)^2: every term is
+    non-negative, so float64 keeps it within a few ulps a block, where the
+    sum of d^2 itself (up to 2^72 at width 12) would not be exact.  With
+    one block every number is the whole cube's one-pass value."""
+    nodes = np.asarray(nodes)
+    B, n = len(nodes), prob.n_inputs
+    plan = block_plan(prob, B, budget)
+    rows = plan.words * 32
+    f = lambda x: np.asarray(x, dtype)
+    edges, mass = gauss_bins(prob.gauss_sigma)
+    ones = np.zeros((B, prob.n_n), np.int64)
+    wrong, wce = np.zeros(B, np.int64), np.zeros(B, np.int64)
+    acc0 = np.ones(B, bool)
+    hist = np.zeros((B, len(edges) + 1), np.int64)
+    # per genome and block, in dtype: the sums of |d|, d, |d| / max(g, 1),
+    # and M2 about the block's mean
+    sums = np.zeros((4, B, plan.blocks), dtype)
+    waiting = collections.deque()
+
+    def take():
+        at, block, future = waiting.popleft()
+        b_wrong, b_wce, b_acc0, b_hist, b_sums = future.result()
+        wrong[at] += b_wrong
+        wce[at] = np.maximum(wce[at], b_wce)
+        acc0[at] &= b_acc0
+        hist[at] += b_hist
+        for total, part in zip(sums, b_sums):
+            total[at, block] = part
+
+    with ThreadPoolExecutor(HOST_THREADS) as pool:
+        gold, at_block = None, None
+        for at, block, block_ones, cand in simulate_blocks(prob, nodes, outs,
+                                                           plan):
+            if block != at_block:
+                gold, at_block = golden_values(
+                    prob.kind, prob.width, block * rows,
+                    (block + 1) * rows)[None, :], block
+            ones[at] += block_ones
+            waiting.append((at, block, pool.submit(
+                _block_partials, gold, cand, edges, dtype)))
+            if len(waiting) > 2 * HOST_THREADS:
+                take()
+        while waiting:
+            take()
+
+    abs_sum, d_sum, rel_sum, m2 = sums
+    out_pct = f(100.0) / f(1 << prob.n_o)
+    mean = _sum(d_sum, dtype) / f(n)
     metrics = np.stack([
-        mae * out_pct,
-        f(ad.max(axis=1)) * out_pct,
-        f(100.0) * f(nz.sum(axis=1)) / f(n),
-        f(100.0) * _sum(rel, dtype) / f(n),
-        np.abs(_sum(diff, dtype) / f(n)) * out_pct,
-        f(~np.any((gold == 0) & (cand != 0), axis=1)),
-        f(gauss_ok),
+        _sum(abs_sum, dtype) / f(n) * out_pct,
+        f(wce) * out_pct,
+        f(100.0) * f(wrong) / f(n),
+        f(100.0) * _sum(rel_sum, dtype) / f(n),
+        np.abs(mean) * out_pct,
+        f(acc0),
+        f(np.all(hist <= f(mass) * f(n), axis=1)),
     ], axis=1)
+    spread = f(rows) * (d_sum / f(rows) - mean[:, None]) ** 2
+    var = (_sum(m2, dtype) + _sum(spread, dtype)) / f(n)
 
     p = f(ones) / f(n)                                        # (B, n_n)
     act = active_gates(prob, nodes, outs)
@@ -302,12 +438,11 @@ def evaluate(prob: Problem, nodes: np.ndarray, outs: np.ndarray,
     leak = f(act) * f(LEAK_NW)[func]
     power = _sum(dyn, dtype) * f(F_CLK_GHZ) + _sum(leak, dtype) * f(LEAK_SCALE)
 
-    mean = _sum(diff, dtype) / f(n)
-    var = _sum((f(diff) - mean[:, None]) ** 2, dtype) / f(n)
     read = np.where(act, func * (n + 1) + np.minimum(ones, n - ones), -1)
     return Evaluation(*(np.asarray(x, np.float64) for x in
                         (metrics, power, mean, np.sqrt(var))), active=act,
-                      power_key=[r.tobytes() for r in read])
+                      power_key=[r.tobytes() for r in read], ones=ones,
+                      hist=hist)
 
 
 def feasible(metrics: np.ndarray, thresholds: np.ndarray) -> np.ndarray:

@@ -72,8 +72,15 @@ def test_third_cell_from_new_files_only(tmp_path):
     assert len(traffic.warmup(cell.mix, 99, 32).constraints) == 3
     prog = run.Program(cell.config, cell.mix["sweep"])
     assert prog.settings == {"dedup": True}
+    # the old cell still resolves, with the metrics the benchmark names
+    # for it, and not the new cell's
     old = cells.resolve("mul8_paper.fig_grid", root)
-    assert len(old.per_layer) == 4
+    listed = json.loads(before[os.path.join(root, "BENCHMARK.json")])
+    assert [m["name"] for m, _ in old.per_layer] == [
+        m["name"] for m in listed["per_layer"]
+        if cells.reports(m, "mul8_paper.fig_grid")]
+    assert old.per_layer and all(callable(read) for _, read in old.per_layer)
+    assert [m["name"] for m in old.end_to_end] == ["evals_per_s", "setup_s"]
 
 
 def test_third_cell_runs_correct(tmp_path):
