@@ -148,9 +148,8 @@ def bench_sweep(width: int = 3, gens: int = 200, lam: int = 4,
     the exhaustive wall: a width-``sampled_width`` multiplier grid whose
     2^(2w) cube (16.7M rows at width 12) no evolve loop could afford,
     evaluated on a ``sampled_size``-row uniform sample instead.  Runs on the
-    jnp backend — the Pallas byte-split ``_exact_sum`` regime is not exact
-    at n_o = 24 (DESIGN.md §9).  Emits ``sampled_runs_per_s``, the key the
-    bench gate tracks.
+    jnp backend (the CPU's; the Pallas kernel is exact there too, DESIGN.md
+    §9).  Emits ``sampled_runs_per_s``, the key the bench gate tracks.
 
     The ``async_commit`` leg times the same grid streamed through the
     results layer chunk-by-chunk (history kept, so the shard payload is

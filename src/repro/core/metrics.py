@@ -12,6 +12,7 @@ Metric vector layout (used by fitness thresholds; see ``fitness.py``):
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -26,18 +27,24 @@ N_METRICS = 7
 class MetricPartials(NamedTuple):
     """Shard-combinable raw sums.  Combine: add all but wce_max (max).
 
-    x64 is disabled (the LM substrate must stay 32-bit), so magnitude sums
-    use an EXACT accumulation (``_exact_sum``) with two statically-chosen
-    regimes.  Historic byte split: |e| = 256*hi + lo with hi/lo ≤ 2^8-1;
-    partial sums of ≤2^16 byte-sized terms stay < 2^24 and are exact in
-    float32; the recombination ``256*hi_sum + lo_sum`` is done in float32
-    whose error is ≤ 1 ulp of the total (relative ~6e-8).  Outside that
-    regime (operands wider than 16 bits or slices longer than 2^16, where
-    the byte split would silently overflow 2^24) the sum switches to per-bit
-    popcounts — int32-exact counts recombined in float32 with error
-    ≤ n_bits ulp (relative ~n_bits·2^-24) — documented precision of the
-    metric pipeline (tests assert rtol 1e-5 vs the int64 NumPy oracle up to
-    width-12 value ranges).
+    x64 is disabled (the LM substrate must stay 32-bit), so the magnitude
+    sums ``abs_sum``/``sgn_sum`` are float32 values of exact integers, by
+    one of two paths.  The Pallas kernel (``kernels/cgp_sim``, the
+    ``backend="pallas"`` path and every sweep's final measurement) keeps
+    Σ|d| and Σd⁺ as base-2^8 digits with a carry, exact through n_o = 24
+    over 2^24 rows, and converts each total to float32 once
+    (``kernels.ops``); its ``moments`` call also keeps the digits of Σd² in
+    ``moments``.  The jnp path (``error_partials``) uses ``_exact_sum``
+    with two statically-chosen regimes.  Byte split: |e| = 256*hi + lo with
+    hi/lo ≤ 2^8-1; partial sums of ≤2^16 byte-sized terms stay < 2^24 and
+    are exact in float32; the recombination ``256*hi_sum + lo_sum`` is done
+    in float32 whose error is ≤ 1 ulp of the total (relative ~6e-8).
+    Outside that regime (operands wider than 16 bits or slices longer than
+    2^16, where the byte split would silently overflow 2^24) the sum
+    switches to per-bit popcounts — int32-exact counts recombined in
+    float32 with error ≤ n_bits ulp (relative ~n_bits·2^-24) — documented
+    precision of the metric pipeline (tests assert rtol 1e-5 vs the int64
+    NumPy oracle up to width-12 value ranges).
     """
     abs_sum: jax.Array    # Σ |g - c|  (float32 via exact split sums)
     wce_max: jax.Array    # max |g - c|
@@ -49,6 +56,10 @@ class MetricPartials(NamedTuple):
     count: jax.Array      # #inputs in this slice
     sq_sum: jax.Array     # Σ (g - c)^2  (float32; variance estimator only)
     rel_sq: jax.Array     # Σ (|g-c| / max(g, 1))^2  (float32)
+    # the CGP kernel's ``moments`` call only: int32 base-2^8 digits of the
+    # exact Σ|g-c|, Σ(g-c) and Σ(g-c)^2 (``kernels.ops.exact_sums`` carries
+    # them)
+    moments: tuple | None = None
 
 
 def gauss_bin_edges(sigma: float, n_side: int = 4) -> np.ndarray:
@@ -97,8 +108,8 @@ def error_partials(golden: jax.Array, cand: jax.Array,
         err_count=nz.sum(),
         rel_sum=(ad.astype(jnp.float32) /
                  jnp.maximum(g, 1).astype(jnp.float32)).sum(),
-        sgn_sum=_exact_sum(jnp.maximum(diff, 0), n_bits) -
-                _exact_sum(jnp.maximum(-diff, 0), n_bits),
+        sgn_sum=_exact_sum(jnp.maximum(diff, 0), n_bits,
+                           minus=jnp.maximum(-diff, 0)),
         acc0_bad=((g == 0) & (c != 0)).sum(),
         hist=hist,
         count=jnp.asarray(diff.shape[0], jnp.int32),
@@ -108,15 +119,17 @@ def error_partials(golden: jax.Array, cand: jax.Array,
     )
 
 
-def _exact_sum(v: jax.Array, n_bits: int = 16) -> jax.Array:
-    """Integer-exact Σv for 0 ≤ v < 2^n_bits (see MetricPartials doc).
+def _exact_sum(v: jax.Array, n_bits: int = 16,
+               minus: jax.Array | None = None) -> jax.Array:
+    """Integer-exact Σv (Σv - Σminus with ``minus``) for 0 ≤ v, minus <
+    2^n_bits (see MetricPartials doc).
 
     The regime is chosen STATICALLY from (n_bits, slice length), never from
     values, so it is jit-stable:
 
       * byte split (historic path) whenever both block sums provably stay
-        < 2^24 — exact, and bit-identical with the Pallas kernel's in-kernel
-        split accumulation for the ≤8-bit-operand cubes the kernel serves;
+        < 2^24 — exact, and rounded once like the Pallas kernel's digits
+        (the two sums' byte columns subtract exactly first);
       * per-bit popcount otherwise: cnt_b = #{v with bit b set} is exact in
         int32 for any slice length (and float32-exact up to 2^24 terms);
         recombining Σ 2^b·cnt_b in float32, ascending, bounds the error at
@@ -127,12 +140,15 @@ def _exact_sum(v: jax.Array, n_bits: int = 16) -> jax.Array:
     n = int(np.prod(v.shape)) if v.shape else 1
     hi_max = max((1 << max(n_bits - 8, 0)) - 1, 0)
     if n * 255 < (1 << 24) and n * hi_max < (1 << 24):
-        hi = (v >> 8).astype(jnp.float32)
-        lo = (v & 0xFF).astype(jnp.float32)
-        return 256.0 * hi.sum() + lo.sum()
+        col = lambda x, f: f(x).astype(jnp.float32).sum()
+        part = lambda f: col(v, f) - (col(minus, f) if minus is not None
+                                      else 0.0)
+        return 256.0 * part(lambda x: x >> 8) + part(lambda x: x & 0xFF)
     total = jnp.float32(0.0)
     for b in range(n_bits):  # ascending: small terms accumulate first
         cnt = ((v >> b) & 1).sum()
+        if minus is not None:
+            cnt = cnt - ((minus >> b) & 1).sum()
         total = total + float(1 << b) * cnt.astype(jnp.float32)
     return total
 
@@ -234,14 +250,27 @@ def metrics_from_values(golden: jax.Array, cand: jax.Array, n_o: int,
     return finalize_metrics(p, n_o, gauss_sigma)
 
 
-def error_moments(golden: jax.Array, cand: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """(mean, std) of the signed error — exact, for Fig. 13-style analysis.
+def exact_error_moments(count, sum_d, sum_sq
+                        ) -> tuple[np.ndarray, np.ndarray]:
+    """(mean, std) float64 of the signed error, on the host, from its exact
+    integer sums Σd and Σd² given as base-2^8 digits (least significant
+    first, the top one signed: ``kernels.ops.exact_sums``).
 
-    int32 is exact here (|g - c| < 2^n_o ≤ 2^31); x64 is disabled repo-wide,
-    so an int64 cast would silently truncate to int32 with a warning anyway.
+    n² Var = n Σd² - (Σd)² is formed in Python integers (Σd² reaches 2^72
+    at width 12), so the only roundings are the two divisions and the
+    square root: an error that is a constant offset has a standard
+    deviation of exactly 0, however large the offset.
     """
-    diff = (golden.astype(jnp.int32) - cand.astype(jnp.int32)).astype(jnp.float32)
-    return diff.mean(), diff.std()
+    count = np.asarray(count)
+    value = lambda d: sum(int(v) << (8 * k) for k, v in enumerate(d))
+    sd = np.asarray(sum_d).reshape(count.size, -1)
+    sq = np.asarray(sum_sq).reshape(count.size, -1)
+    mean, std = np.zeros(count.size), np.zeros(count.size)
+    for i, n in enumerate(count.reshape(-1)):
+        n, s1, s2 = max(int(n), 1), value(sd[i]), value(sq[i])
+        mean[i] = s1 / n
+        std[i] = math.sqrt((n * s2 - s1 * s1) / (n * n))
+    return mean.reshape(count.shape), std.reshape(count.shape)
 
 
 # ------------------------- NumPy oracle (tests) -------------------------

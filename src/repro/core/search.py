@@ -108,34 +108,28 @@ def run_search(cfg: SearchConfig, constraint: ConstraintSpec,
 def characterize(genome: Genome, spec: CGPSpec, cfg: SearchConfig,
                  constraint: ConstraintSpec, seed: int,
                  in_planes, gvals, gpower) -> CircuitRecord:
-    """Full final measurement of an evolved circuit."""
-    wires = simulate.simulate_planes(genome, spec, in_planes)
-    cvals = simulate.unpack_values(wires[genome.outs])
-    partials = M.error_partials(gvals, cvals, constraint.gauss_sigma,
-                                n_bits=spec.n_o)
-    met = M.finalize_metrics(partials, spec.n_o, constraint.gauss_sigma)
-    if cfg.evolve.eval_mode == "sampled":
-        stderr = np.asarray(M.metric_stderr(partials, spec.n_o))
-    else:  # census: zero sampling error by construction
-        stderr = np.zeros(M.N_METRICS, np.float32)
-    probs = simulate.signal_probabilities(wires[spec.n_i:])
-    cost = circuit_cost_from_probs(genome, spec, probs)
-    emean, estd = M.error_moments(gvals, cvals)
-    from repro.core.fitness import feasible as feas_fn
-    feas = feas_fn(met, jnp.asarray(constraint.thresholds()))
+    """Full final measurement of an evolved circuit: the batched sweep's
+    ``characterize_chunk`` on a batch of one."""
+    from repro.core.sweep import characterize_chunk, finish_moments
+    sampled = cfg.evolve.eval_mode == "sampled"
+    met, stderr, prel, feas, count, moments = characterize_chunk(
+        spec, constraint.gauss_sigma, genome.nodes[None], genome.outs[None],
+        jnp.asarray(constraint.thresholds())[None], in_planes, gvals, gpower,
+        sampled=sampled)
+    emean, estd = finish_moments(count, moments)
     return CircuitRecord(
         genome_nodes=np.asarray(genome.nodes),
         genome_outs=np.asarray(genome.outs),
-        metrics=np.asarray(met),
-        power_rel=float(cost.power / gpower),
+        metrics=np.asarray(met[0]),
+        power_rel=float(prel[0]),
         constraint=constraint.describe(),
         seed=seed,
-        feasible=bool(feas),
-        error_mean=float(emean),
-        error_std=float(estd),
-        metrics_stderr=stderr,
+        feasible=bool(feas[0]),
+        error_mean=float(emean[0]),
+        error_std=float(estd[0]),
+        metrics_stderr=np.asarray(stderr[0]),
         # serial path has no escalation driver: exact iff exhaustive
-        certified=cfg.evolve.eval_mode != "sampled",
+        certified=not sampled,
     )
 
 

@@ -73,7 +73,6 @@ from repro.checkpoint import store
 from repro.core import certify
 from repro.core import metrics as M
 from repro.core import migrate as migrate_mod
-from repro.core import simulate
 from repro.core.commit import ChunkCommitter
 from repro.core.evalcache import PhenotypeLRU
 from repro.core.results import (SweepResultReader, SweepResultWriter,
@@ -84,6 +83,7 @@ from repro.core.evolve import (EvolveConfig, eval_segment, init_state_batched,
 from repro.core.fitness import ConstraintSpec, feasible
 from repro.core.genome import CGPSpec, Genome, phenotype_digests
 from repro.core.power import circuit_cost_from_probs
+from repro.kernels import ops as kops
 
 
 @dataclasses.dataclass(frozen=True)
@@ -490,30 +490,58 @@ def characterize_chunk(spec: CGPSpec, gauss_sigma: float, nodes: jax.Array,
                        outs: jax.Array, thr_mat: jax.Array,
                        in_planes: jax.Array, golden_vals: jax.Array,
                        golden_power: jax.Array, sampled: bool = False):
-    """Vmapped final measurement (metrics + power/delay + error moments).
+    """Final measurement of stacked genomes (metrics, power with the delay
+    scan, feasibility, error moments) in one CGP kernel dispatch.
 
-    ``sampled`` additionally turns the second-moment partials into per-metric
-    standard errors (DESIGN.md §9); exhaustive chunks report zeros (a census
-    has no sampling error) so the returned tuple shape is mode-invariant.
+    Returns (metrics, stderr, power_rel, feasible, count, moments):
+    ``sampled`` turns the second-moment partials into per-metric standard
+    errors (DESIGN.md §9), zeros otherwise (a census has no sampling error);
+    ``moments`` holds the digits of the exact Σd and Σd², which
+    ``finish_moments`` turns into the signed error's mean and standard
+    deviation on the host.
     """
-    def one(n, o, thr):
-        g = Genome(n, o)
-        wires = simulate.simulate_planes(g, spec, in_planes)
-        cvals = simulate.unpack_values(wires[g.outs])
-        partials = M.error_partials(golden_vals, cvals, gauss_sigma,
-                                    n_bits=spec.n_o)
-        met = M.finalize_metrics(partials, spec.n_o, gauss_sigma)
-        if sampled:
-            sterr = M.metric_stderr(partials, spec.n_o)
-        else:
-            sterr = jnp.zeros((M.N_METRICS,), jnp.float32)
-        probs = simulate.signal_probabilities(wires[spec.n_i:])
-        cost = circuit_cost_from_probs(g, spec, probs)
-        emean, estd = M.error_moments(golden_vals, cvals)
-        return (met, sterr, cost.power / golden_power, feasible(met, thr),
-                emean, estd)
+    genomes = Genome(nodes, outs)
+    with jax.named_scope("characterize.kernel"):
+        partials, pops = kops.cgp_eval_batched(
+            genomes, spec, in_planes, golden_vals, gauss_sigma,
+            moments=True)
+    with jax.named_scope("characterize.moments"):
+        moments = kops.exact_sums(partials.moments)[1:]
+    finalize = lambda p: M.finalize_metrics(p, spec.n_o, gauss_sigma)
+    met = jax.vmap(finalize)(partials)
+    if sampled:
+        sterr = jax.vmap(lambda p: M.metric_stderr(p, spec.n_o))(partials)
+    else:
+        sterr = jnp.zeros(met.shape, jnp.float32)
+    probs = pops / partials.count.astype(jnp.float32)[:, None]
+    cost = jax.vmap(lambda g, p: circuit_cost_from_probs(g, spec, p))(
+        genomes, probs)
+    return (met, sterr, cost.power / golden_power,
+            jax.vmap(feasible)(met, thr_mat), partials.count, moments)
 
-    return jax.vmap(one)(nodes, outs, thr_mat)
+
+@functools.lru_cache(maxsize=None)
+def _replicated_characterize_fn(mesh, spec: CGPSpec, gauss_sigma: float,
+                                sampled: bool):
+    """``characterize_chunk`` on every chip of ``mesh``, each over the whole
+    cube (gathered where a cube-sharded sweep holds it sharded): the
+    records of a cube-sharded sweep are the unsharded sweep's, bit for
+    bit."""
+    from jax.sharding import PartitionSpec as P
+
+    def call(*args):
+        return characterize_chunk(spec, gauss_sigma, *args, sampled=sampled)
+
+    return jax.jit(jax.shard_map(call, mesh=mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False))
+
+
+def finish_moments(count, moments) -> tuple[np.ndarray, np.ndarray]:
+    """float32 (mean, std) of the signed error from ``characterize_chunk``'s
+    ``count`` and ``moments``, in exact integer arithmetic on the host."""
+    mean, std = M.exact_error_moments(np.asarray(count),
+                                      *(np.asarray(d) for d in moments))
+    return mean.astype(np.float32), std.astype(np.float32)
 
 
 # --------------------------------------------------------------------------
@@ -880,10 +908,16 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
                     state, hp, hm, hf = evolve_chunk(
                         spec, ecfg, gold, jnp.asarray(thr[sel]), in_planes,
                         gvals, gpower, jnp.asarray(keys[sel]))
-                met, sterr, prel, feas, emean, estd = characterize_chunk(
-                    spec, sigma, state.parent.nodes, state.parent.outs,
-                    jnp.asarray(thr[sel]), in_planes, gvals, gpower,
-                    sampled=sampled)
+                final = (state.parent.nodes, state.parent.outs,
+                         jnp.asarray(thr[sel]), in_planes, gvals, gpower)
+                if sweep.model_axis is not None:
+                    met, sterr, prel, feas, count, moments = \
+                        _replicated_characterize_fn(
+                            ctx.get_mesh(), spec, sigma, sampled)(*final)
+                else:
+                    met, sterr, prel, feas, count, moments = \
+                        characterize_chunk(spec, sigma, *final,
+                                           sampled=sampled)
 
             # histories stay in RAM ("full") or spill per chunk to a shard
             keep_hist = mode == "full" or (writer is not None
@@ -898,9 +932,10 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
                     ("metrics", met),
                     ("metrics_stderr", sterr),
                     ("power_rel", prel),
-                    ("feasible", feas),
-                    ("error_mean", emean),
-                    ("error_std", estd))}
+                    ("feasible", feas))}
+                chunk_rows["error_mean"], chunk_rows["error_std"] = \
+                    finish_moments(np.asarray(count)[:n],
+                                   [np.asarray(d)[:n] for d in moments])
                 hist_rows = {key: np.asarray(value)[:n] for key, value in (
                     ("hist_power_rel", hp), ("hist_fit", hf),
                     ("hist_metrics", hm))} if keep_hist else {}

@@ -27,7 +27,8 @@ grid runs sequentially with the LAST dimension innermost):
 Both layouts write ONE ``(R, cols)`` float32 accumulator that stays whole in
 VMEM for the entire grid (its block index never changes, so it is written
 back to HBM once, after the last step).  Grid step (genome r, block w) sets
-row r at w == 0 and combines into it afterwards, so every genome's cube
+row r at w == 0 and combines into it afterwards (then carries the digits of
+the exact sums, below), so every genome's cube
 blocks accumulate in the same ascending order in either layout and the
 outputs are bit-identical (including the float32 ``rel_sum`` column) —
 layout is a pure execution knob, picked per (width, R, backend) by
@@ -51,20 +52,46 @@ v5e chip to keep them honest:
   contraction — counts ≤ 32 are exact in bfloat16 and the float32 sums stay
   integer-exact.
 
-Accumulator row (float32, ``n_wires = n_i + n_n``):
+Accumulator row (float32, ``n_wires = n_i + n_n``; ``cols`` rounds it up
+to a multiple of 8, and the wire scratch has ``cols`` rows):
   ``[0, n_wires)``                    per-wire popcounts (input planes first;
                                       the wrapper returns the gate rows)
-  ``n_wires + [0, N_SUMS)``           the split-sum partials below
-  ``n_wires + N_SUMS``                worst-case |error| (max-combined)
-  ``n_wires + N_SUMS + 1 + [0, n_bins)``  signed-error histogram
-  padding up to ``cols`` (a multiple of 8)
+  ``n_wires + [0, n_sums)``           the sums below: ``N_SUMS`` columns,
+                                      ``N_SUMS_MOMENTS`` with ``moments``
+  ``n_wires + n_sums``                worst-case |error| (max-combined)
+  ``n_wires + n_sums + 1 + [0, n_bins)``  signed-error histogram
+
+The sums: ``ERR_CNT``, ``ACC0_BAD``, ``COUNT`` and the histogram are counts
+of rows and the popcounts counts of set bits, so at most the ``MAX_ROWS``
+= 2^24 rows a call may cover: float32 holds every such integer exactly.
+WCE is below 2^24 (``MAX_N_O`` = 24 output bits).  ``REL_SUM``, ``SQ_SUM``
+and ``REL_SQ`` are float32 sums (MRE, and the sampled mode's variance
+estimators).  Σ|d| and Σd⁺ (Σd = 2 Σd⁺ - Σ|d|) reach 2^48 at n_o = 24
+over 2^24 rows, so each is kept exact as ``SUM_DIGITS`` base-2^8 digits
+(``ABS_DIGITS``, ``POS_DIGITS``): a block adds, to digit k, the block sum
+of byte k of each row's value (below 2^23 for blocks of at most 2^15
+rows), and every combine carries each digit's excess over 2^8 one lane up
+(``pltpu.roll``), so every digit stays below 2^24 and the float32
+accumulator stores it exactly; the top digit keeps its excess.  Where the
+whole cube's digit sums stay below 2^24 (at most 2^16 rows: width 8),
+the kernel leaves out the carry, a static choice.  The
+wrapper returns every digit fully carried (``carry_digits``), so a total
+has one row however the cube was cut into blocks or shards.  With
+``moments`` (the sweep's final measurement, ``core.sweep.
+characterize_chunk``) the ``SQ_DIGITS`` digits of Σd² (below 2^72) follow,
+built the same way from the bytes of the products of the bytes of |d|
+(blocks of at most 2^14 rows).  ``kernels.ops`` decodes the digits: Σ|d|
+and Σd converted to float32 once, and all three exactly for the moments.
+A call that could leave these bounds raises ValueError.
 
 VMEM budget at the paper scale (8x8 multiplier, 400 nodes, block=512 words,
-R=256): wire scratch (440, 512) int32 ≈ 0.9 MB; input-plane block 32 KB and
-golden block 64 KB (double-buffered); accumulator 256 × 440 × 4 B ≈ 0.45 MB
-(double-buffered) — ~2.2 MB in all.  The accumulator grows by ~1.7 KB per
-genome, so a dispatch stays inside the 16 MiB scoped-VMEM default up to a few
-thousand genomes.
+R=256): wire scratch (448, 512) int32 ≈ 0.9 MB; input-plane block 32 KB and
+golden block 64 KB (double-buffered); accumulator 256 × 448 × 4 B ≈ 0.46 MB
+(double-buffered) — ~2.2 MB in all.  At width 12 (900 nodes, R=256): wire
+scratch (960, 512) int32 ≈ 2 MB, input-plane block 48 KB, golden 64 KB,
+accumulator 256 × 960 × 4 B ≈ 1 MB (double-buffered) — ~4.2 MB.  The
+accumulator grows by ``4 cols`` B per genome, so a dispatch stays inside
+the 16 MiB scoped-VMEM default up to a few thousand genomes.
 """
 from __future__ import annotations
 
@@ -79,14 +106,60 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import gates
 from repro.core import metrics as M
 
-# sums vector layout (float32): exact split accumulation, see core.metrics.
-# SQ_SUM/REL_SQ are appended second-moment rows (float32, variance estimators
-# for the sampled-eval confidence intervals, DESIGN.md §9) — appended LAST so
-# the historic row indices (and hence the exhaustive-path bit patterns of
-# every pre-existing row) are unchanged.
-ABS_HI, ABS_LO, ERR_CNT, REL_SUM, POS_HI, POS_LO, NEG_HI, NEG_LO, \
-    ACC0_BAD, COUNT, SQ_SUM, REL_SQ = range(12)
-N_SUMS = 12
+# the sums (layout in the module doc): float32 totals first, then the
+# base-2^8 digits of the exact integer sums
+ERR_CNT, REL_SUM, ACC0_BAD, COUNT, SQ_SUM, REL_SQ = range(6)
+DIGIT_BITS = 8
+SUM_DIGITS = 6                     # Σ|d|, Σd⁺ < 2^24 rows × 2^24 = 2^48
+SQ_DIGITS = 9                      # Σd² < 2^24 rows × 2^48 = 2^72
+ABS_DIGITS = 6
+POS_DIGITS = ABS_DIGITS + SUM_DIGITS
+SQ_DIGITS_AT = POS_DIGITS + SUM_DIGITS
+N_SUMS = SQ_DIGITS_AT              # columns of a call without ``moments``
+N_SUMS_MOMENTS = N_SUMS + SQ_DIGITS  # and with
+#: the widest call the counts and digits hold exactly
+MAX_ROWS = 1 << 24
+MAX_N_O = 24
+_EXACT = 1 << 24                   # float32 holds every integer up to it
+
+
+def n_sums(moments: bool) -> int:
+    return N_SUMS_MOMENTS if moments else N_SUMS
+
+
+def _n_bytes(n_o: int) -> int:
+    return -(-n_o // DIGIT_BITS)
+
+
+def _byte(v: jax.Array, j: int, n_bytes: int) -> jax.Array:
+    """Byte ``j`` of ``0 <= v < 2^(8 n_bytes)``; the top byte keeps the
+    rest (below 2^8 too, as ``v`` has no more bits)."""
+    v = v >> (DIGIT_BITS * j) if j else v
+    return v if j == n_bytes - 1 else v & 0xFF
+
+
+def _square_parts(n_bytes: int) -> list[tuple[int, int, int, tuple]]:
+    """(i, j, weight, ts) of each product of bytes of |d|: d² = Σ_{i<=j}
+    weight 2^(8(i+j)) x_i x_j over the bytes x of |d| (weight 2 where
+    i < j), and byte t in ``ts`` of the product (below 2^17) goes to digit
+    i + j + t; bytes that are always 0 are left out."""
+    out = []
+    for i in range(n_bytes):
+        for j in range(i, n_bytes):
+            w = 1 if i == j else 2
+            out.append((i, j, w, tuple(
+                t for t in range(3) if (w * 255 * 255) >> (DIGIT_BITS * t))))
+    return out
+
+
+def _block_bound(n_bytes: int, moments: bool) -> int:
+    """The most one row adds to one digit column."""
+    bound = {}
+    for i, j, w, ts in _square_parts(n_bytes) if moments else ():
+        for t in ts:
+            bound[i + j + t] = bound.get(i + j + t, 0) + min(
+                (w * 255 * 255) >> (DIGIT_BITS * t), 255)
+    return max([255] + list(bound.values()))
 
 
 def _as_i32(v: int) -> int:
@@ -110,7 +183,8 @@ def _gate_eval(func: jax.Array, a: jax.Array, b: jax.Array,
 
 
 def _block_row(genome_ref, planes_ref, golden_ref, wires, *, n_i: int,
-               n_n: int, n_o: int, edges: tuple[float, ...]) -> jax.Array:
+               n_n: int, n_o: int, edges: tuple[float, ...],
+               moments: bool) -> jax.Array:
     """One genome × one cube block: netlist walk + fused metric partials,
     returned as a ``(1, cols)`` accumulator row (layout in the module doc)."""
     bw = planes_ref.shape[1]
@@ -150,19 +224,33 @@ def _block_row(genome_ref, planes_ref, golden_ref, wires, *, n_i: int,
     nz = diff != 0
     f32 = lambda x: x.astype(jnp.float32)
     tot = lambda x: jnp.sum(f32(x), keepdims=True)  # (1, 1)
-    pos, neg = jnp.maximum(diff, 0), jnp.maximum(-diff, 0)
+    pos = jnp.maximum(diff, 0)
     adf = f32(ad)
     relf = adf / f32(jnp.maximum(g, 1))
-    sums = [None] * N_SUMS
-    sums[ABS_HI], sums[ABS_LO] = tot(ad >> 8), tot(ad & 0xFF)
-    sums[POS_HI], sums[POS_LO] = tot(pos >> 8), tot(pos & 0xFF)
-    sums[NEG_HI], sums[NEG_LO] = tot(neg >> 8), tot(neg & 0xFF)
+    sums = [None] * n_sums(moments)
     sums[ERR_CNT] = tot(nz)
     sums[REL_SUM] = tot(relf)
     sums[ACC0_BAD] = tot((g == 0) & (vals != 0))
     sums[COUNT] = jnp.full((1, 1), 32.0 * bw, jnp.float32)
     sums[SQ_SUM] = tot(adf * adf)
     sums[REL_SQ] = tot(relf * relf)
+    # digit j of Σ|d| and Σd⁺ takes the block sum of byte j of each row
+    nb = _n_bytes(n_o)
+    for j in range(nb):
+        sums[ABS_DIGITS + j] = tot(_byte(ad, j, nb))
+        sums[POS_DIGITS + j] = tot(_byte(pos, j, nb))
+    if moments:  # Σd², from the bytes of the products of bytes of |d|
+        x = [_byte(ad, j, nb) for j in range(nb)]
+        parts = {}
+        for i, j, w, ts in _square_parts(nb):
+            p = x[i] * x[j] * w
+            for t in ts:
+                b = (p >> (DIGIT_BITS * t)) if t else p
+                b = b & 0xFF if t < 2 else b
+                k = i + j + t
+                parts[k] = b + parts[k] if k in parts else b
+        for k, part in parts.items():
+            sums[SQ_DIGITS_AT + k] = tot(part)
     wce = jnp.max(adf, keepdims=True)
 
     # histogram = searchsorted(edges, diff, side="right") over nonzero
@@ -175,20 +263,67 @@ def _block_row(genome_ref, planes_ref, golden_ref, wires, *, n_i: int,
     col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
     row = jnp.where(col < n_wires, pops, 0.0)
     for j, v in enumerate(sums + [wce] + hist):
-        row = jnp.where(col == n_wires + j, v, row)
+        if v is not None:  # digits no byte reaches stay 0
+            row = jnp.where(col == n_wires + j, v, row)
     return row
+
+
+def carry_digits(d: jax.Array) -> jax.Array:
+    """Digits (..., K) int32 carried to base 2^8, least significant first:
+    every digit but the top in [0, 2^8), the top one signed (an arithmetic
+    shift floors)."""
+    d = [d[..., k] for k in range(d.shape[-1])]
+    for k in range(len(d) - 1):
+        d[k + 1] = d[k + 1] + (d[k] >> DIGIT_BITS)
+        d[k] = d[k] & ((1 << DIGIT_BITS) - 1)
+    return jnp.stack(d, axis=-1)
+
+
+def _digit_groups(moments: bool) -> tuple[tuple[int, int], ...]:
+    """(first column among the sums, digits) of each exact sum: the sums'
+    last columns."""
+    return ((ABS_DIGITS, SUM_DIGITS), (POS_DIGITS, SUM_DIGITS)) + (
+        ((SQ_DIGITS_AT, SQ_DIGITS),) if moments else ())
+
+
+def _canonical(sums: jax.Array) -> jax.Array:
+    """``sums`` with each exact sum's digits fully carried: one value has
+    one row, however the cube was cut into blocks or shards."""
+    groups = _digit_groups(sums.shape[-1] == N_SUMS_MOMENTS)
+    return jnp.concatenate([sums[..., :ABS_DIGITS]] + [
+        carry_digits(sums[..., at:at + n].astype(jnp.int32)).astype(
+            jnp.float32) for at, n in groups], axis=-1)
+
+
+def _carry(row: jax.Array, s: int, moments: bool) -> jax.Array:
+    """One carry step over the digit columns of a row whose sums start at
+    column ``s``: each digit but a sum's top keeps its low 8 bits and
+    hands the rest one lane up, to the next digit of its sum.  Digits
+    below 2^24 come out below 2^8 + 2^16."""
+    col = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    digit = (col >= s + ABS_DIGITS) & (col < s + n_sums(moments))
+    low = digit
+    for at, n in _digit_groups(moments):
+        low = low & (col != s + at + n - 1)
+    d = row.astype(jnp.int32)
+    up = pltpu.roll(jnp.where(low, d >> DIGIT_BITS, 0), 1, 1)
+    d = jnp.where(low, d & ((1 << DIGIT_BITS) - 1), d) + up
+    return jnp.where(digit, d.astype(jnp.float32), row)
 
 
 def cgp_sim_kernel(genome_ref, planes_ref, golden_ref, acc_ref, wires, *,
                    genome_axis: int, n_i: int, n_n: int, n_o: int,
-                   edges: tuple[float, ...]):
+                   edges: tuple[float, ...], moments: bool, carry: bool):
     """Grid step (genome r, cube block w) in either layout: row r of the
-    VMEM-resident accumulator is set at w == 0 and combined into after."""
+    VMEM-resident accumulator is set at w == 0 and combined into after:
+    the WCE column by max, the others by addition, then (``carry``) the
+    digits carry."""
     r = pl.program_id(genome_axis)
     blk = pl.program_id(1 - genome_axis)
     row = _block_row(genome_ref, planes_ref, golden_ref, wires, n_i=n_i,
-                     n_n=n_n, n_o=n_o, edges=edges)
+                     n_n=n_n, n_o=n_o, edges=edges, moments=moments)
     acc = acc_ref.at[pl.ds(r, 1), :]
+    s = n_i + n_n
 
     @pl.when(blk == 0)
     def _init():
@@ -198,8 +333,9 @@ def cgp_sim_kernel(genome_ref, planes_ref, golden_ref, acc_ref, wires, *,
     def _combine():
         prev = acc[...]
         col = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
-        acc[...] = jnp.where(col == n_i + n_n + N_SUMS,  # the WCE column
-                             jnp.maximum(prev, row), prev + row)
+        new = jnp.where(col == s + n_sums(moments),  # the WCE column
+                        jnp.maximum(prev, row), prev + row)
+        acc[...] = _carry(new, s, moments) if carry else new
 
 
 def _resolve_interpret(interpret: bool | None) -> bool:
@@ -217,13 +353,15 @@ def _resolve_interpret(interpret: bool | None) -> bool:
 @functools.partial(
     jax.jit,
     static_argnames=("n_i", "n_n", "n_o", "gauss_sigma", "n_gauss_side",
-                     "block_words", "r_tile", "layout", "interpret"))
+                     "block_words", "r_tile", "layout", "moments",
+                     "interpret"))
 def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
                             in_planes: jax.Array, golden_vals: jax.Array,
                             *, n_i: int, n_n: int, n_o: int,
                             gauss_sigma: float = 256.0, n_gauss_side: int = 4,
                             block_words: int = 512, r_tile: int = 8,
                             layout: str = "genome_major",
+                            moments: bool = False,
                             interpret: bool | None = None):
     """Fused (runs × λ) pallas_call: ONE dispatch for R stacked genomes.
 
@@ -241,11 +379,16 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
         across layouts; resolve ``"auto"`` upstream (``kernels.tune`` /
         ``ops.cgp_eval_batched``) — this function only accepts the two
         concrete spellings.
+      moments: add the digits of Σd² to the sums.
       interpret: Pallas interpret mode; ``None`` takes
         ``ops.default_interpret()`` (on only off-TPU).
     Returns per-genome accumulators
-      (sums (R, N_SUMS) f32, wce (R, 1) i32, hist (R, n_bins) f32,
+      (sums (R, n_sums(moments)) f32, wce (R, 1) i32, hist (R, n_bins) f32,
        pops (R, n_n) f32).
+
+    Raises ValueError where a count or digit could leave float32's exact
+    range: more than ``MAX_ROWS`` rows, more than ``MAX_N_O`` outputs, or
+    a block whose digit sums could pass 2^24.
     """
     if layout not in ("genome_major", "cube_major"):
         raise ValueError(
@@ -263,18 +406,32 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
     W = in_planes.shape[1]
     bw = min(block_words, W)
     assert W % bw == 0, (W, bw)
+    if 32 * W > MAX_ROWS or n_o > MAX_N_O:
+        raise ValueError(
+            f"{32 * W} rows and {n_o} outputs: the kernel's counts are exact "
+            f"up to {MAX_ROWS} rows and |d| up to {MAX_N_O} bits")
+    # a block's digit sum plus a carried digit (< 2^8 + 2^16) stays exact;
+    # where even the whole cube's digit sums stay exact (the width-8
+    # cubes), nothing carries in the kernel: with the carry, the width-8
+    # kernel ran 1.4% slower on a TPU v5e (PERF.md section 6)
+    bound = _block_bound(_n_bytes(n_o), moments)
+    if 32 * bw * bound + (1 << 8) + (1 << 16) > _EXACT:
+        raise ValueError(f"block_words={bw} lets a digit pass 2^24")
+    carry = 32 * W * bound >= _EXACT
     edges = tuple(float(e) for e in np.float32(
         M.gauss_bin_edges(gauss_sigma, n_gauss_side)))
     n_bins = len(edges) + 1
     n_wires = n_i + n_n
-    cols = -(-(n_wires + N_SUMS + 1 + n_bins) // 8) * 8
+    n_s = n_sums(moments)
+    cols = -(-(n_wires + n_s + 1 + n_bins) // 8) * 8
 
     genome_axis = 0 if layout == "genome_major" else 1
     grid = (Rp, W // bw) if genome_axis == 0 else (W // bw, Rp)
     genome_of = lambda *ij: ij[genome_axis]
     block_of = lambda *ij: ij[1 - genome_axis]
     kernel = functools.partial(cgp_sim_kernel, genome_axis=genome_axis,
-                               n_i=n_i, n_n=n_n, n_o=n_o, edges=edges)
+                               n_i=n_i, n_n=n_n, n_o=n_o, edges=edges,
+                               moments=moments, carry=carry)
     acc = pl.pallas_call(
         kernel,
         grid=grid,
@@ -291,9 +448,9 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
         interpret=interpret,
     )(genomes[:, None], in_planes, golden_vals.reshape(W, 32).T)[:R]
     s = n_wires
-    return (acc[:, s:s + N_SUMS],
-            acc[:, s + N_SUMS:s + N_SUMS + 1].astype(jnp.int32),
-            acc[:, s + N_SUMS + 1:s + N_SUMS + 1 + n_bins],
+    return (_canonical(acc[:, s:s + n_s]),
+            acc[:, s + n_s:s + n_s + 1].astype(jnp.int32),
+            acc[:, s + n_s + 1:s + n_s + 1 + n_bins],
             acc[:, n_i:n_wires])
 
 
@@ -314,11 +471,12 @@ def cgp_sim_metrics_batched_sharded(nodes: jax.Array, outs: jax.Array,
     ``in_planes``/``golden_vals`` slice, then the per-genome accumulators
     combine across the axis — sums/histogram/popcounts (and the count row
     inside ``sums``) psum, the worst-case-error row pmax.  The psum contract
-    stays exact for the integer-valued accumulators: every per-shard split
-    sum is an integer < 2^24, so float32 psum is associative on them and the
-    combined partials are bit-identical to the unsharded kernel's
-    (``rel_sum`` alone is genuinely floating-point and only
-    reassociation-close).
+    stays exact for the integer-valued accumulators: every per-shard count
+    is at most the 2^24 rows of the whole cube (``MAX_ROWS`` checked on the
+    whole), and every per-shard digit comes carried, below 2^8, so float32
+    psum is associative on them; carried again after the psum, the digits
+    are the unsharded kernel's, bit for bit (``rel_sum`` alone is
+    genuinely floating-point and only reassociation-close).
 
     This is what lets a pod's whole (chunk × λ) population fuse into one
     dispatch per generation even when the cube is sharded —
@@ -330,12 +488,15 @@ def cgp_sim_metrics_batched_sharded(nodes: jax.Array, outs: jax.Array,
     — this shard's word slice.  Must be called inside a context where
     ``axis_name`` is bound (it is not independently jit-able).
     """
+    if 32 * in_planes.shape[1] * jax.lax.axis_size(axis_name) > MAX_ROWS:
+        raise ValueError(f"the sharded cube passes {MAX_ROWS} rows")
     sums, wce, hist, pops = cgp_sim_metrics_batched(
         nodes, outs, in_planes, golden_vals, n_i=n_i, n_n=n_n, n_o=n_o,
         gauss_sigma=gauss_sigma, n_gauss_side=n_gauss_side,
         block_words=block_words, r_tile=r_tile, layout=layout,
         interpret=interpret)
-    return (jax.lax.psum(sums, axis_name), jax.lax.pmax(wce, axis_name),
+    return (_canonical(jax.lax.psum(sums, axis_name)),
+            jax.lax.pmax(wce, axis_name),
             jax.lax.psum(hist, axis_name), jax.lax.psum(pops, axis_name))
 
 

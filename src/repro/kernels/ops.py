@@ -47,22 +47,55 @@ def default_interpret() -> bool:
     return _INTERPRET_DEFAULT
 
 
+def _digits_value(d: jax.Array) -> jax.Array:
+    """float32 of the integer that ``SUM_DIGITS`` carried digits hold (below
+    2^48 in magnitude), rounded once: two 24-bit halves, each exact."""
+    half = lambda k: d[..., k] + (d[..., k + 1] << 8) + (d[..., k + 2] << 16)
+    return half(3).astype(jnp.float32) * float(1 << 24) + \
+        half(0).astype(jnp.float32)
+
+
+def _digit_columns(sums: jax.Array) -> tuple[jax.Array, ...]:
+    """The kernel's digits of Σ|d| and Σd = 2 Σd⁺ - Σ|d| (and of Σd² in a
+    ``moments`` call), as int32 and not yet carried."""
+    C = _cgp
+    digits = lambda at, n: sums[..., at:at + n].astype(jnp.int32)
+    a, p = digits(C.ABS_DIGITS, C.SUM_DIGITS), digits(C.POS_DIGITS,
+                                                       C.SUM_DIGITS)
+    out = (a, 2 * p - a)
+    if sums.shape[-1] == C.N_SUMS_MOMENTS:
+        out += (digits(C.SQ_DIGITS_AT, C.SQ_DIGITS),)
+    return out
+
+
+def exact_sums(digits: tuple[jax.Array, ...]) -> tuple[jax.Array, ...]:
+    """The digits of a ``MetricPartials.moments`` carried to base 2^8
+    (int32, least significant first, the top one signed): Σ|d|, Σd and
+    Σd² exactly, for ``core.metrics.exact_error_moments`` to finish on
+    the host."""
+    return tuple(_cgp.carry_digits(d) for d in digits)
+
+
 def _partials_from_sums(sums: jax.Array, wce: jax.Array, hist: jax.Array
                         ) -> M.MetricPartials:
-    """Decode the kernel's (..., N_SUMS) split-sum rows into MetricPartials."""
+    """Decode the kernel's (..., n_sums) rows into MetricPartials: the
+    exact Σ|d| and Σd converted to float32 once; a ``moments`` call's
+    digits kept in ``moments``."""
     C = _cgp
+    digits = _digit_columns(sums)
+    abs_digits, sgn_digits = (_cgp.carry_digits(d) for d in digits[:2])
     return M.MetricPartials(
-        abs_sum=256.0 * sums[..., C.ABS_HI] + sums[..., C.ABS_LO],
+        abs_sum=_digits_value(abs_digits),
         wce_max=wce[..., 0],
         err_count=sums[..., C.ERR_CNT].astype(jnp.int32),
         rel_sum=sums[..., C.REL_SUM],
-        sgn_sum=(256.0 * sums[..., C.POS_HI] + sums[..., C.POS_LO])
-                - (256.0 * sums[..., C.NEG_HI] + sums[..., C.NEG_LO]),
+        sgn_sum=_digits_value(sgn_digits),
         acc0_bad=sums[..., C.ACC0_BAD].astype(jnp.int32),
         hist=hist.astype(jnp.int32),
         count=sums[..., C.COUNT].astype(jnp.int32),
         sq_sum=sums[..., C.SQ_SUM],
         rel_sq=sums[..., C.REL_SQ],
+        moments=digits if len(digits) == 3 else None,
     )
 
 
@@ -89,15 +122,16 @@ def cgp_eval_batched(genomes: Genome, spec: CGPSpec, in_planes: jax.Array,
                      block_words: int | None = None,
                      interpret: bool | None = None,
                      r_tile: int | None = None, axis_name: str | None = None,
-                     layout: str = "auto"
-                     ) -> tuple[M.MetricPartials, jax.Array]:
+                     layout: str = "auto", moments: bool = False):
     """Fused (runs × λ) population evaluation in ONE kernel dispatch.
 
     ``genomes`` carries a leading stacked axis R: nodes (R, n_n, 3), outs
     (R, n_o).  The genome axis becomes Pallas grid dimension 0 — this
     replaces ``jax.vmap(cgp_eval)`` over a population, which dispatched one
     kernel per genome (or one vmap-batched program) and left the run axis
-    off the grid.  Returns (MetricPartials with leading R, pops (R, n_n)).
+    off the grid.  Returns (MetricPartials with leading R, pops (R, n_n));
+    with ``moments`` (unsharded only) the partials' ``moments`` hold the
+    digits of the exact Σ|d|, Σd and Σd² (``exact_sums``).
 
     With ``axis_name`` the input cube is sharded over that mesh axis:
     ``in_planes``/``golden_vals`` are this shard's word slice, the local
@@ -150,7 +184,10 @@ def cgp_eval_batched(genomes: Genome, spec: CGPSpec, in_planes: jax.Array,
               r_tile=r_tile, layout=layout, interpret=interpret)
     if axis_name is None:
         sums, wce, hist, pops = _cgp.cgp_sim_metrics_batched(
-            genomes.nodes, genomes.outs, in_planes, golden_vals, **kw)
+            genomes.nodes, genomes.outs, in_planes, golden_vals,
+            moments=moments, **kw)
+    elif moments:
+        raise ValueError("the moments are measured on the whole cube")
     else:
         sums, wce, hist, pops = _cgp.cgp_sim_metrics_batched_sharded(
             genomes.nodes, genomes.outs, in_planes, golden_vals,

@@ -4,10 +4,10 @@ Three-way comparison per genome: ``cgp_sim_metrics_batched`` (genome axis on
 the Pallas grid) vs the per-genome ``cgp_sim_metrics`` vs the pure-jnp oracle
 ``ref.cgp_eval_ref`` — across widths, gauss sigmas, block sizes and ragged R
 (R not a multiple of the genome-axis pad width).  All integer-valued metric
-partials and the per-gate popcounts must be BIT-identical (the split-sum
-accumulators are exact in float32); ``rel_sum`` is a float32 division
-reduction that XLA may reassociate differently across program shapes, so it
-gets allclose.
+partials and the per-gate popcounts must be BIT-identical (the exact
+sums are rounded once to float32 on every path); ``rel_sum`` is a float32
+division reduction that XLA may reassociate differently across program
+shapes, so it gets allclose.
 
 Also: the exhaustive ``_gate_eval`` truth-table property test and the
 interpret-mode auto-detect regression test.

@@ -13,6 +13,8 @@ from repro.kernels import ops, ref
 
 def _assert_partials_close(pk, pr, rtol=1e-5):
     for name in pk._fields:
+        if getattr(pk, name) is None is getattr(pr, name):
+            continue  # ``moments``: only the kernel's moments call has them
         a, b = np.asarray(getattr(pk, name)), np.asarray(getattr(pr, name))
         np.testing.assert_allclose(a, b, rtol=rtol, atol=1e-6,
                                    err_msg=f"partial {name}")

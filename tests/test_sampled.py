@@ -245,6 +245,8 @@ def test_sampled_partials_combine_like_cube_shards():
                                jnp.asarray(cvals[i:j]), 256.0, n_bits=8)
               for i, j in ((0, half), (half, len(gvals)))]
     for name in M.MetricPartials._fields:
+        if name == "moments":  # the kernel's moments call alone has them
+            continue
         a, b = (getattr(s, name) for s in shards)
         comb = np.maximum(a, b) if name == "wce_max" else a + b
         np.testing.assert_allclose(np.asarray(comb),

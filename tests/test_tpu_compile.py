@@ -83,6 +83,41 @@ def test_cgp_kernel_compiles_at_paper_shape(one_chip, layout):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("moments", [False, True])
+def test_cgp_kernel_compiles_at_width_12(one_chip, moments):
+    """The exhaustive 12x12 multiplier: 900 nodes, the 2^24-row cube in
+    1,024 blocks of 512 words, 256 genomes a dispatch, the exact carried
+    digits (and with ``moments`` those of the sum of squares)."""
+    n_i = n_o = 24
+    w12, n_n = (1 << n_i) // 32, 900
+    i32 = lambda *s: _shape(s, jnp.int32, one_chip)
+    fn = jax.jit(lambda n, o, p, g: cgp_sim.cgp_sim_metrics_batched(
+        n, o, p, g, n_i=n_i, n_n=n_n, n_o=n_o, block_words=512,
+        layout="genome_major", moments=moments, interpret=False))
+    compiled = fn.lower(i32(R, n_n, 3), i32(R, n_o), i32(n_i, w12),
+                        i32(w12 * 32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_characterize_chunk_compiles_at_width_12(one_chip,
+                                                 compiled_kernels):
+    """The sweep's final measurement of a width-12 chunk goes through the
+    kernel: no (genomes, wires, words) array of its own."""
+    n_i = n_o = 24
+    w12, n_n = (1 << n_i) // 32, 900
+    spec = CGPSpec(n_i=n_i, n_o=n_o, n_n=n_n)
+    i32 = lambda *s: _shape(s, jnp.int32, one_chip)
+    compiled = sweep.characterize_chunk.lower(
+        spec, 256.0, i32(CHUNK, n_n, 3), i32(CHUNK, n_o),
+        _shape((CHUNK, 7), jnp.float32, one_chip), i32(n_i, w12),
+        i32(w12 * 32), _shape((), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the cube's inputs and golden values, and its transpose: well under
+    # the chip's 16 GB, where a simulation of 32 genomes held 62 GB
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1 << 30
+
+
 def test_cube_sharded_sweep_chunk_compiles_on_four_chips(topo,
                                                          compiled_kernels):
     """The ``SweepConfig(model_axis="model")`` chunk program over a 2x2 host:
