@@ -42,15 +42,22 @@ checks none of them, ``tests/test_tpu_compile.py`` compiles for a described
 v5e chip to keep them honest:
 
 * a genome (nodes and outs flattened into one int32 row) is an SMEM block,
-  read as scalars: node fan-ins are dynamic row indices into the wire plane;
-* every VMEM block is ``(8k, 128k)`` or spans the full dimension: input
-  planes ``(n_i, bw)``, golden values ``(32, bw)`` of the transposed
-  ``(32, W)`` golden array (row = bit lane, column = word), the accumulator
-  whole;
+  read as scalars: node fan-ins are dynamic indices into the wire plane;
+* a block of ``bw`` words is sublane-dense (``wire_shape``): ``S`` sublanes
+  of ``L`` lanes, ``L = 128`` where ``bw`` is a multiple of it (``S = bw /
+  128``) and else ``L = bw``, ``S = 1``.  Wire ``k`` of the block is rows
+  ``[k S, (k + 1) S)`` of the ``(cols S, L)`` scratch, so at ``S >= 8`` every
+  load, store and vector op of the netlist walk fills whole ``(8, 128)``
+  vregs (one row of a ``(cols, bw)`` plane filled 1 sublane of 8);
+* every VMEM block spans its last two dimensions: input planes
+  ``(n_i, S, L)`` and golden values ``(32, S, L)`` (bit, then the block's
+  words) cut from ``(rows, W / bw, S, L)`` arrays (squeezed to ``(rows, L)``
+  at ``S = 1``), the accumulator whole;
 * the accumulator row is assembled with lane selects, never a scatter, and
   the per-wire popcounts (taken on int32) become a row through one MXU
-  contraction — counts ≤ 32 are exact in bfloat16 and the float32 sums stay
-  integer-exact.
+  contraction per sublane (a strided read of that sublane of every wire) —
+  counts ≤ 32 are exact in bfloat16, and the float32 sums (≤ 32 L a
+  contraction, then S of them) stay integer-exact.
 
 Accumulator row (float32, ``n_wires = n_i + n_n``; ``cols`` rounds it up
 to a multiple of 8, and the wire scratch has ``cols`` rows):
@@ -74,7 +81,11 @@ rows), and every combine carries each digit's excess over 2^8 one lane up
 (``pltpu.roll``), so every digit stays below 2^24 and the float32
 accumulator stores it exactly; the top digit keeps its excess.  Where the
 whole cube's digit sums stay below 2^24 (at most 2^16 rows: width 8),
-the kernel leaves out the carry, a static choice.  The
+the kernel leaves out the carry, a static choice, and a block may be the
+whole cube.  ``default_block_words`` takes the largest block these bounds
+allow: 2,048 words (the whole cube, ``(16, 128)``) at width 8, 1,024
+(``(8, 128)``) where the kernel carries, 512 (``(4, 128)``) with
+``moments``.  The
 wrapper returns every digit fully carried (``carry_digits``), so a total
 has one row however the cube was cut into blocks or shards.  With
 ``moments`` (the sweep's final measurement, ``core.sweep.
@@ -84,14 +95,16 @@ built the same way from the bytes of the products of the bytes of |d|
 and Σd converted to float32 once, and all three exactly for the moments.
 A call that could leave these bounds raises ValueError.
 
-VMEM budget at the paper scale (8x8 multiplier, 400 nodes, block=512 words,
-R=256): wire scratch (448, 512) int32 ≈ 0.9 MB; input-plane block 32 KB and
-golden block 64 KB (double-buffered); accumulator 256 × 448 × 4 B ≈ 0.46 MB
-(double-buffered) — ~2.2 MB in all.  At width 12 (900 nodes, R=256): wire
-scratch (960, 512) int32 ≈ 2 MB, input-plane block 48 KB, golden 64 KB,
-accumulator 256 × 960 × 4 B ≈ 1 MB (double-buffered) — ~4.2 MB.  The
-accumulator grows by ``4 cols`` B per genome, so a dispatch stays inside
-the 16 MiB scoped-VMEM default up to a few thousand genomes.
+VMEM budget at the paper scale (8x8 multiplier, 400 nodes, the whole
+2,048-word cube a block, S = 16, R=256): wire scratch (448 × 16, 128) int32
+≈ 3.7 MB; input-plane block (16, 16, 128) 128 KB and golden block (32, 16,
+128) 256 KB (double-buffered); accumulator 256 × 448 × 4 B ≈ 0.46 MB
+(double-buffered) — ~4.5 MB in all.  At width 12 (900 nodes, 1,024 words,
+S = 8, R=256): wire scratch (960 × 8, 128) int32 ≈ 3.9 MB, input-plane
+block 96 KB, golden 128 KB, accumulator 256 × 960 × 4 B ≈ 1 MB (all
+double-buffered) — ~6.3 MB.  The accumulator grows by ``4 cols`` B per
+genome, so a dispatch stays inside the 16 MiB scoped-VMEM default up to a
+few thousand genomes.
 """
 from __future__ import annotations
 
@@ -121,6 +134,7 @@ N_SUMS_MOMENTS = N_SUMS + SQ_DIGITS  # and with
 MAX_ROWS = 1 << 24
 MAX_N_O = 24
 _EXACT = 1 << 24                   # float32 holds every integer up to it
+LANES = 128
 
 
 def n_sums(moments: bool) -> int:
@@ -162,6 +176,34 @@ def _block_bound(n_bytes: int, moments: bool) -> int:
     return max([255] + list(bound.values()))
 
 
+def _carry_slack(W: int, n_o: int, moments: bool) -> int:
+    """What a carried digit may hold (below 2^8 + 2^16) where a W-word
+    cube's digit sums could pass 2^24 and the kernel carries; 0 where they
+    cannot (the width-8 cubes): with the carry, the width-8 kernel ran
+    1.4% slower on a TPU v5e (PERF.md section 6)."""
+    carries = 32 * W * _block_bound(_n_bytes(n_o), moments) >= _EXACT
+    return (1 << 8) + (1 << 16) if carries else 0
+
+
+def default_block_words(W: int, n_o: int, moments: bool) -> int:
+    """The largest block of a W-word cube (W halved until it fits) whose
+    digit sums stay exact: 2,048 words (the whole cube) at width 8, 1,024
+    at width 12, 512 with ``moments``."""
+    bound = _block_bound(_n_bytes(n_o), moments)
+    bw = W
+    while bw > 1 and 32 * bw * bound + _carry_slack(W, n_o, moments) \
+            > _EXACT:
+        bw //= 2
+    return bw
+
+
+def wire_shape(bw: int) -> tuple[int, int]:
+    """(S, L): a block of ``bw`` words as S sublanes of L lanes, dense
+    wherever ``bw`` is a multiple of the 128 lanes; else one row (S = 1)."""
+    L = LANES if bw % LANES == 0 else bw
+    return bw // L, L
+
+
 def _as_i32(v: int) -> int:
     """A 32-bit pattern as the Python int of the same int32 bits."""
     return v - (1 << 32) if v >= 1 << 31 else v
@@ -182,48 +224,30 @@ def _gate_eval(func: jax.Array, a: jax.Array, b: jax.Array,
     return (m0 & s(0)) | (m1 & s(1)) | (m2 & s(2)) | (m3 & s(3))
 
 
-def _block_row(genome_ref, planes_ref, golden_ref, wires, *, n_i: int,
-               n_n: int, n_o: int, edges: tuple[float, ...],
-               moments: bool) -> jax.Array:
-    """One genome × one cube block: netlist walk + fused metric partials,
-    returned as a ``(1, cols)`` accumulator row (layout in the module doc)."""
-    bw = planes_ref.shape[1]
-    cols = wires.shape[0]
-    n_wires = n_i + n_n
+def _fold(x: jax.Array, reduce=jnp.sum) -> jax.Array:
+    """``x`` reduced over its leading bit axis: (S, L) from a (32, S, L)
+    slab, elementwise across vregs; (1, L) from a (32, L) one."""
+    return reduce(x, axis=0, keepdims=x.ndim == 2)
 
-    # --- phase 1: netlist walk over the VMEM wire plane -------------------
-    wires[0:n_i, :] = planes_ref[...]
 
-    def node_step(k, _):
-        a = wires[pl.ds(genome_ref[0, 3 * k], 1), :]
-        b = wires[pl.ds(genome_ref[0, 3 * k + 1], 1), :]
-        wires[pl.ds(n_i + k, 1), :] = _gate_eval(genome_ref[0, 3 * k + 2],
-                                                 a, b)
-        return 0
-
-    jax.lax.fori_loop(0, n_n, node_step, 0)
-
-    # --- phase 2: per-wire popcounts as one row: ones · popcount(wires)^T --
-    # (rows past n_wires hold stale scratch; their lanes are overwritten)
-    counts = jax.lax.population_count(wires[...]).astype(jnp.float32)
-    pops = jax.lax.dot_general(
-        jnp.ones((8, bw), jnp.bfloat16), counts.astype(jnp.bfloat16),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)[0:1]
-
-    # --- phase 3: unpack outputs, fuse metric partials ---------------------
-    # bit b of word w is input 32*w + b: row b, column w of the golden block
-    bit = jax.lax.broadcasted_iota(jnp.int32, (32, bw), 0)
-    vals = jnp.zeros((32, bw), jnp.int32)
-    for o in range(n_o):  # static unroll: n_o is small (<= 2*width)
-        plane = wires[pl.ds(genome_ref[0, 3 * n_n + o], 1), :]  # (1, bw)
+def _slab_partials(g: jax.Array, planes: list[jax.Array], *, n_o: int,
+                   edges: tuple[float, ...], moments: bool
+                   ) -> tuple[list, jax.Array, list]:
+    """Unpack the outputs of one slab of a block and fold each metric
+    partial over the bit axis: (sums, wce, edge counts), each an array of
+    the slab's word shape, ``None`` for digits no byte reaches."""
+    # bit b of word w is input 32*w + b: row b of the golden slab; an
+    # output plane (S, L) broadcasts over the leading bit axis, a (1, L)
+    # one over sublanes
+    bit = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    vals = jnp.zeros(g.shape, jnp.int32)
+    for o, plane in enumerate(planes):  # static unroll: n_o <= 24
         vals = vals | (((plane >> bit) & 1) << o)
-
-    g = golden_ref[...]
     diff = g - vals
     ad = jnp.abs(diff)
     nz = diff != 0
     f32 = lambda x: x.astype(jnp.float32)
-    tot = lambda x: jnp.sum(f32(x), keepdims=True)  # (1, 1)
+    tot = lambda x: _fold(f32(x))
     pos = jnp.maximum(diff, 0)
     adf = f32(ad)
     relf = adf / f32(jnp.maximum(g, 1))
@@ -231,7 +255,6 @@ def _block_row(genome_ref, planes_ref, golden_ref, wires, *, n_i: int,
     sums[ERR_CNT] = tot(nz)
     sums[REL_SUM] = tot(relf)
     sums[ACC0_BAD] = tot((g == 0) & (vals != 0))
-    sums[COUNT] = jnp.full((1, 1), 32.0 * bw, jnp.float32)
     sums[SQ_SUM] = tot(adf * adf)
     sums[REL_SQ] = tot(relf * relf)
     # digit j of Σ|d| and Σd⁺ takes the block sum of byte j of each row
@@ -251,13 +274,83 @@ def _block_row(genome_ref, planes_ref, golden_ref, wires, *, n_i: int,
                 parts[k] = b + parts[k] if k in parts else b
         for k, part in parts.items():
             sums[SQ_DIGITS_AT + k] = tot(part)
-    wce = jnp.max(adf, keepdims=True)
-
     # histogram = searchsorted(edges, diff, side="right") over nonzero
     # errors, as differences of "diff >= edge" counts (exact integers)
     dfl = f32(diff)
-    ge = [sums[ERR_CNT]] + [tot(nz & (dfl >= e)) for e in edges]
-    ge.append(jnp.zeros((1, 1), jnp.float32))
+    ge = [tot(nz & (dfl >= e)) for e in edges]
+    return sums, _fold(adf, jnp.max), ge
+
+
+def _block_row(genome_ref, planes_ref, golden_ref, wires, *, n_i: int,
+               n_n: int, n_o: int, edges: tuple[float, ...],
+               moments: bool) -> jax.Array:
+    """One genome × one cube block: netlist walk + fused metric partials,
+    returned as a ``(1, cols)`` accumulator row (layout in the module doc).
+
+    A wire's block of ``bw = S * L`` words is ``S`` rows of the ``(cols *
+    S, L)`` scratch, sublane-dense: every vector op of the walk works on
+    whole vregs once ``S >= 8``."""
+    L = wires.shape[1]
+    S = golden_ref.shape[1] if golden_ref.ndim == 3 else 1
+    cols = wires.shape[0] // S
+    n_wires = n_i + n_n
+    bw = S * L
+    at = lambda w: pl.multiple_of(w * S, S) if S > 1 else w
+    wire = lambda w, s0=0, n=S: wires[pl.ds(at(w) + s0, n), :]
+
+    # --- phase 1: netlist walk over the VMEM wire plane -------------------
+    if S == 1:
+        wires[0:n_i, :] = planes_ref[...]
+    else:
+        for i in range(n_i):
+            wires[i * S:(i + 1) * S, :] = planes_ref[i]
+
+    def node_step(k, _):
+        a = wire(genome_ref[0, 3 * k])
+        b = wire(genome_ref[0, 3 * k + 1])
+        wires[pl.ds(at(n_i + k), S), :] = _gate_eval(
+            genome_ref[0, 3 * k + 2], a, b)
+        return 0
+
+    jax.lax.fori_loop(0, n_n, node_step, 0)
+
+    # --- phase 2: per-wire popcounts as one row: ones · popcount(wires)^T --
+    # per sublane s (a strided read of row s of every wire): each count is
+    # at most 32, exact in bfloat16, and each contraction's sums (at most
+    # 32 L) are exact in float32, as are their S-term totals.  Rows past
+    # n_wires hold stale scratch; their lanes are overwritten.
+    ones = jnp.ones((8, L), jnp.bfloat16)
+    pops = None
+    for s in range(S):
+        w = wires[pl.ds(s, cols, stride=S), :] if S > 1 else wires[...]
+        counts = jax.lax.population_count(w).astype(jnp.float32)
+        p = jax.lax.dot_general(
+            ones, counts.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)[0:1]
+        pops = p if pops is None else pops + p
+
+    # --- phase 3: unpack outputs, fuse metric partials ---------------------
+    # in slabs of at most 8 sublanes, so that the live values stay near
+    # the register file; each partial folds to the word shape, slabs add
+    outs = [genome_ref[0, 3 * n_n + o] for o in range(n_o)]
+    n = min(S, 8)
+    sums = None
+    for s0 in range(0, S, n):
+        g = golden_ref[:, s0:s0 + n, :] if S > 1 else golden_ref[...]
+        ps, pw, pg = _slab_partials(g, [wire(o, s0, n) for o in outs],
+                                    n_o=n_o, edges=edges, moments=moments)
+        if sums is None:
+            sums, wce, ge = ps, pw, pg
+        else:
+            sums = [a if a is None else a + b for a, b in zip(sums, ps)]
+            wce = jnp.maximum(wce, pw)
+            ge = [a + b for a, b in zip(ge, pg)]
+    tot = lambda x: jnp.sum(x, keepdims=True)  # (1, 1)
+    sums = [v if v is None else tot(v) for v in sums]
+    sums[COUNT] = jnp.full((1, 1), 32.0 * bw, jnp.float32)
+    wce = jnp.max(wce, keepdims=True)
+    ge = [sums[ERR_CNT]] + [tot(v) for v in ge] + [
+        jnp.zeros((1, 1), jnp.float32)]
     hist = [ge[b] - ge[b + 1] for b in range(len(edges) + 1)]
 
     col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
@@ -359,7 +452,7 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
                             in_planes: jax.Array, golden_vals: jax.Array,
                             *, n_i: int, n_n: int, n_o: int,
                             gauss_sigma: float = 256.0, n_gauss_side: int = 4,
-                            block_words: int = 512, r_tile: int = 8,
+                            block_words: int | None = None, r_tile: int = 8,
                             layout: str = "genome_major",
                             moments: bool = False,
                             interpret: bool | None = None):
@@ -369,6 +462,8 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
       nodes: (R, n_n, 3) int32 stacked genomes; outs: (R, n_o) int32.
       in_planes: (n_i, W) int32 — shared across the genome axis.
       golden_vals: (W*32,) int32 — shared across the genome axis.
+      block_words: words of the cube a grid step takes; ``None`` takes
+        ``default_block_words``.
       r_tile: pad of the genome axis; R is padded up to a multiple with
         copies of the last genome, sliced off on return, so ragged R (e.g. a
         non-multiple sweep-chunk tail) is transparent.
@@ -404,20 +499,17 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
                                        genomes.shape[1:])])
     Rp = R + r_pad
     W = in_planes.shape[1]
-    bw = min(block_words, W)
-    assert W % bw == 0, (W, bw)
     if 32 * W > MAX_ROWS or n_o > MAX_N_O:
         raise ValueError(
             f"{32 * W} rows and {n_o} outputs: the kernel's counts are exact "
             f"up to {MAX_ROWS} rows and |d| up to {MAX_N_O} bits")
-    # a block's digit sum plus a carried digit (< 2^8 + 2^16) stays exact;
-    # where even the whole cube's digit sums stay exact (the width-8
-    # cubes), nothing carries in the kernel: with the carry, the width-8
-    # kernel ran 1.4% slower on a TPU v5e (PERF.md section 6)
-    bound = _block_bound(_n_bytes(n_o), moments)
-    if 32 * bw * bound + (1 << 8) + (1 << 16) > _EXACT:
+    bw = default_block_words(W, n_o, moments) if block_words is None \
+        else min(block_words, W)
+    assert W % bw == 0, (W, bw)
+    if 32 * bw * _block_bound(_n_bytes(n_o), moments) + _carry_slack(
+            W, n_o, moments) > _EXACT:
         raise ValueError(f"block_words={bw} lets a digit pass 2^24")
-    carry = 32 * W * bound >= _EXACT
+    S, L = wire_shape(bw)
     edges = tuple(float(e) for e in np.float32(
         M.gauss_bin_edges(gauss_sigma, n_gauss_side)))
     n_bins = len(edges) + 1
@@ -428,10 +520,15 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
     genome_axis = 0 if layout == "genome_major" else 1
     grid = (Rp, W // bw) if genome_axis == 0 else (W // bw, Rp)
     genome_of = lambda *ij: ij[genome_axis]
-    block_of = lambda *ij: ij[1 - genome_axis]
+    block_of = lambda *ij: (0, ij[1 - genome_axis], 0, 0)
+    # (rows, W) -> (rows, blocks, S, L): a block's words sublane-dense; S
+    # is a whole dimension, so any S is a legal block (squeezed at S = 1)
+    dense = lambda x: x.reshape(x.shape[0], W // bw, S, L)
+    words = (S, L) if S > 1 else (None, L)
     kernel = functools.partial(cgp_sim_kernel, genome_axis=genome_axis,
                                n_i=n_i, n_n=n_n, n_o=n_o, edges=edges,
-                               moments=moments, carry=carry)
+                               moments=moments,
+                               carry=_carry_slack(W, n_o, moments) > 0)
     acc = pl.pallas_call(
         kernel,
         grid=grid,
@@ -439,14 +536,15 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
             pl.BlockSpec((None, 1, genomes.shape[1]),
                          lambda *ij: (genome_of(*ij), 0, 0),
                          memory_space=pltpu.SMEM),           # one genome
-            pl.BlockSpec((n_i, bw), lambda *ij: (0, block_of(*ij))),
-            pl.BlockSpec((32, bw), lambda *ij: (0, block_of(*ij))),
+            pl.BlockSpec((n_i, None) + words, block_of),
+            pl.BlockSpec((32, None) + words, block_of),
         ],
         out_specs=pl.BlockSpec((Rp, cols), lambda *ij: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, cols), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((cols, bw), jnp.int32)],  # wire plane
+        scratch_shapes=[pltpu.VMEM((cols * S, L), jnp.int32)],  # wires
         interpret=interpret,
-    )(genomes[:, None], in_planes, golden_vals.reshape(W, 32).T)[:R]
+    )(genomes[:, None], dense(in_planes),
+      dense(golden_vals.reshape(W, 32).T))[:R]
     s = n_wires
     return (_canonical(acc[:, s:s + n_s]),
             acc[:, s + n_s:s + n_s + 1].astype(jnp.int32),
@@ -460,7 +558,8 @@ def cgp_sim_metrics_batched_sharded(nodes: jax.Array, outs: jax.Array,
                                     axis_name: str, n_i: int, n_n: int,
                                     n_o: int, gauss_sigma: float = 256.0,
                                     n_gauss_side: int = 4,
-                                    block_words: int = 512, r_tile: int = 8,
+                                    block_words: int | None = None,
+                                    r_tile: int = 8,
                                     layout: str = "genome_major",
                                     interpret: bool | None = None):
     """Cube-shard variant of the fused batched kernel (DESIGN.md §6).
@@ -507,7 +606,8 @@ def cgp_sim_metrics_batched_sharded(nodes: jax.Array, outs: jax.Array,
 def cgp_sim_metrics(nodes: jax.Array, outs: jax.Array, in_planes: jax.Array,
                     golden_vals: jax.Array, *, n_i: int, n_n: int, n_o: int,
                     gauss_sigma: float = 256.0, n_gauss_side: int = 4,
-                    block_words: int = 512, interpret: bool | None = None):
+                    block_words: int | None = None,
+                    interpret: bool | None = None):
     """Per-genome wrapper.  Returns (sums(N_SUMS,), wce(1,), hist, pops(n_n,)).
 
     in_planes: (n_i, W) int32; golden_vals: (W*32,) int32.  Delegates to the

@@ -101,7 +101,8 @@ def _partials_from_sums(sums: jax.Array, wce: jax.Array, hist: jax.Array
 
 def cgp_eval(genome: Genome, spec: CGPSpec, in_planes: jax.Array,
              golden_vals: jax.Array, gauss_sigma: float = 256.0,
-             block_words: int = 512, interpret: bool | None = None
+             block_words: int | None = None,
+             interpret: bool | None = None
              ) -> tuple[M.MetricPartials, jax.Array]:
     """Fused candidate evaluation -> (MetricPartials, per-gate popcounts).
 
@@ -147,9 +148,11 @@ def cgp_eval_batched(genomes: Genome, spec: CGPSpec, in_planes: jax.Array,
     the tuning pass times them jointly (``kernels.tune.resolve_variant``,
     keyed by (width, R, backend); a half-adopted variant could be slower
     than either the winner or the default).  With an explicit layout, or no
-    table entry, the defaults are 512 words and the interpret-aware pad
-    (8 when compiled, 1 interpreted: interpret mode pays every pad row as
-    a full recomputed evaluation).  Passing either knob explicitly overrides the
+    table entry, the defaults are the largest block whose digit sums stay
+    exact (``cgp_sim.default_block_words``: 2,048 words at width 8, 1,024
+    at width 12, 512 with ``moments``) and the interpret-aware pad (8 when
+    compiled, 1 interpreted: interpret mode pays every pad row as a full
+    recomputed evaluation).  Passing either knob explicitly overrides the
     tuned value for that knob only.
 
     ``layout`` picks the evaluation-grid order (DESIGN.md §7):
@@ -172,10 +175,11 @@ def cgp_eval_batched(genomes: Genome, spec: CGPSpec, in_planes: jax.Array,
             _tune.backend_key(interpret),
             default=_tune.KernelVariant(r_tile=1 if interpret else 8))
         layout = variant.layout
-    if block_words is None:
+    if block_words is None and variant is not None:
         # tuned blocks are measured per width, and every candidate is a
-        # power of two, so they divide any (power-of-two) cube shard too
-        block_words = variant.block_words if variant is not None else 512
+        # power of two, so they divide any (power-of-two) cube shard too;
+        # None (no table entry) leaves the block to the kernel's shape rule
+        block_words = variant.block_words
     if r_tile is None:
         r_tile = variant.r_tile if variant is not None \
             else (1 if interpret else 8)

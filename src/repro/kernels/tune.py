@@ -49,14 +49,14 @@ DEFAULT_TABLE = os.path.join(_ROOT, "experiments", "tuning",
                              "kernel_layout.json")
 
 # candidate cube block sizes (words); clipped to the cube width per problem
-BLOCK_CANDIDATES = (128, 256, 512)
+BLOCK_CANDIDATES = (128, 256, 512, 1024, 2048)
 
 
 @dataclasses.dataclass(frozen=True)
 class KernelVariant:
     """One point of the kernel execution space (results-invariant knobs)."""
     layout: str = DEFAULT_LAYOUT
-    block_words: int = 512
+    block_words: int | None = None  # None: the kernel's shape rule
     r_tile: int = 8
 
     def key(self) -> str:
@@ -144,14 +144,19 @@ def default_variants(n_words: int, interpret: bool,
     """Registry of candidate variants for a cube of ``n_words`` words.
 
     Both layouts × every candidate block size that divides the cube (the
-    kernel requires ``W % bw == 0``); interpret mode pays every pad row as a
-    recomputed evaluation, so its registry pins ``r_tile=1`` while compiled
-    candidates use the sublane-aligned 8.
+    kernel requires ``W % bw == 0``) and keeps its digit sums exact (1,024
+    words at most where the kernel carries); interpret mode pays every pad
+    row as a recomputed evaluation, so its registry pins ``r_tile=1``
+    while compiled candidates use the sublane-aligned 8.
     """
+    from repro.kernels import cgp_sim
     if r_tiles is None:
         r_tiles = (1,) if interpret else (8,)
+    # the tuned calls have no moments, whose bound alone depends on n_o
+    largest = cgp_sim.default_block_words(n_words, 1, moments=False)
     blocks = sorted({min(b, n_words) for b in BLOCK_CANDIDATES
-                     if n_words % min(b, n_words) == 0})
+                     if n_words % min(b, n_words) == 0
+                     and min(b, n_words) <= largest})
     return [KernelVariant(layout=layout, block_words=bw, r_tile=rt)
             for layout in LAYOUTS for bw in blocks for rt in r_tiles]
 
@@ -187,7 +192,7 @@ def resolve_variant(width: int, R: int, backend: str,
     if hit is None:
         return default if default is not None else KernelVariant()
     return KernelVariant(layout=hit.get("layout", DEFAULT_LAYOUT),
-                         block_words=int(hit.get("block_words", 512)),
+                         block_words=hit.get("block_words"),
                          r_tile=int(hit.get("r_tile", 8)))
 
 

@@ -42,6 +42,8 @@ def _stacked_genomes(spec: CGPSpec, R: int, seed: int = 0) -> Genome:
     (4, 120, 8, 8, 48.0),    # W == bw, R exactly on the pad boundary
     (4, 120, 4, 9, 256.0),   # R just past the pad boundary
     (8, 150, 512, 2, 256.0),  # paper-scale cube, lane-aligned block
+    (8, 150, 1024, 2, 256.0),  # (8, 128) wire blocks
+    (8, 150, None, 2, 256.0),  # the default: the whole cube, (16, 128)
 ])
 def test_batched_kernel_differential(width, n_n, block, R, sigma):
     spec = CGPSpec(n_i=2 * width, n_o=2 * width, n_n=n_n)

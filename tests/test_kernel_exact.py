@@ -4,7 +4,9 @@ At widths 9 and 10 a genome's Σ|d|, Σd and Σd² pass 2^24 (Σd² passes
 2^48), so a float32 total of byte slices would round them.
 Random genomes run in interpret mode with small ``block_words`` so that
 their cube spans many blocks and every combine carries; each partial is
-held against an int64 NumPy oracle, integers exactly.  Then the final
+held against an int64 NumPy oracle, integers exactly.  The same oracle
+holds every sublane-dense wire block, ``(1, L)`` to ``(16, 128)``, in both
+grid layouts, and their integer outputs are bit-identical.  Then the final
 measurement (``core.sweep.characterize_chunk``), now one kernel dispatch,
 against ``metrics_np`` and float64 moments, on random genomes and on
 errors that are a constant offset.
@@ -59,6 +61,34 @@ def _value(digits) -> int:
                for k, v in enumerate(np.asarray(digits)))
 
 
+def _assert_exact(out, genomes, spec, gold, moments) -> list[dict]:
+    """A kernel call's outputs against the oracle, integers exactly: the
+    carried digits of the exact sums, the counts, WCE, the histogram and
+    the per-gate popcounts.  Returns the oracle's partials."""
+    C = cgp_sim
+    sums, wce, hist, pops = (np.asarray(a) for a in out)
+    digits = lambda row, at, n: _value(row[at:at + n].astype(np.int64))
+    wants = []
+    for i in range(sums.shape[0]):
+        want = _oracle(np.asarray(genomes.nodes[i]),
+                       np.asarray(genomes.outs[i]), spec, gold,
+                       M.gauss_bin_edges(256.0))
+        row = sums[i]
+        assert digits(row, C.ABS_DIGITS, C.SUM_DIGITS) == want["abs"]
+        assert 2 * digits(row, C.POS_DIGITS, C.SUM_DIGITS) - want["abs"] \
+            == want["sgn"]
+        if moments:
+            assert digits(row, C.SQ_DIGITS_AT, C.SQ_DIGITS) == want["sq"]
+        assert int(row[C.ERR_CNT]) == want["err"]
+        assert int(row[C.ACC0_BAD]) == want["acc0"]
+        assert int(row[C.COUNT]) == want["count"]
+        assert int(wce[i, 0]) == want["wce"]
+        np.testing.assert_array_equal(hist[i].astype(np.int64), want["hist"])
+        np.testing.assert_array_equal(pops[i].astype(np.int64), want["pops"])
+        wants.append(want)
+    return wants
+
+
 @pytest.mark.parametrize("width,n_n,block", [
     (9, 24, 8),     # 1,024 blocks of 256 rows
     (10, 24, 16),   # 2,048 blocks of 512 rows
@@ -75,27 +105,93 @@ def test_kernel_partials_exact_past_2_24(width, n_n, block):
         r_tile=1, moments=True, interpret=True)
     p = ops._partials_from_sums(sums, wce, hist)
     abs_d, sgn_d, sq_d = ops.exact_sums(p.moments)
-    edges = M.gauss_bin_edges(256.0)
-    for i in range(3):
-        want = _oracle(np.asarray(genomes.nodes[i]),
-                       np.asarray(genomes.outs[i]), spec, gold, edges)
+    wants = _assert_exact((sums, wce, hist, pops), genomes, spec, gold,
+                          moments=True)
+    for i, want in enumerate(wants):
         # the float32 byte-split totals would have rounded these
         assert want["abs"] > 1 << 24 and want["sq"] > 1 << 48
         assert _value(abs_d[i]) == want["abs"]
         assert _value(sgn_d[i]) == want["sgn"]
         assert _value(sq_d[i]) == want["sq"]
-        row = np.asarray(sums[i]).astype(np.int64)
-        assert row[cgp_sim.ERR_CNT] == want["err"]
-        assert row[cgp_sim.ACC0_BAD] == want["acc0"]
-        assert row[cgp_sim.COUNT] == want["count"]
-        assert int(wce[i, 0]) == want["wce"]
-        np.testing.assert_array_equal(np.asarray(hist[i]).astype(np.int64),
-                                      want["hist"])
-        np.testing.assert_array_equal(np.asarray(pops[i]).astype(np.int64),
-                                      want["pops"])
         # decoded once to float32: the exact totals, rounded once
         assert float(p.abs_sum[i]) == float(np.float32(want["abs"]))
         assert float(p.sgn_sum[i]) == float(np.float32(want["sgn"]))
+
+
+#: the float32 sums, whose order follows the block shape
+FLOAT_SUMS = [cgp_sim.REL_SUM, cgp_sim.SQ_SUM, cgp_sim.REL_SQ]
+
+
+@pytest.mark.parametrize("moments", [False, True])
+@pytest.mark.parametrize("width,n_o,blocks", [
+    # a cube under 128 words: one row of L = block words (S = 1)
+    pytest.param(5, 10, (8, 32), id="S1"),
+    # (1, 128), (4, 128), (8, 128), (16, 128); with the moments the
+    # kernel carries, and blocks past 512 words are refused
+    pytest.param(8, 16, (128, 512, 1024, 2048), id="w8-mul"),
+    # the product's high byte (n_o 8): the moments' digits stay exact
+    # over the whole cube, so the dense blocks take them too
+    pytest.param(8, 8, (512, 1024, 2048), id="w8-high-byte"),
+])
+def test_dense_wire_blocks_exact_and_bit_identical(width, n_o, blocks,
+                                                   moments):
+    """Every wire block shape, in both layouts: the oracle's integers
+    exactly, integer outputs bit-identical across blocks and layouts, the
+    float32 sums equal up to their summation order."""
+    spec = CGPSpec(n_i=2 * width, n_o=n_o, n_n=16)
+    planes = S.input_planes(spec.n_i)
+    gold = G.golden_values(width, "mul") >> (2 * width - n_o)
+    genomes = _genomes(spec, 2, seed=width + n_o)
+    W = planes.shape[1]
+    first = None
+    for bw in blocks:
+        for layout in ("genome_major", "cube_major"):
+            call = lambda: cgp_sim.cgp_sim_metrics_batched(
+                genomes.nodes, genomes.outs, planes, jnp.asarray(gold),
+                n_i=spec.n_i, n_n=spec.n_n, n_o=spec.n_o, block_words=bw,
+                r_tile=1, layout=layout, moments=moments, interpret=True)
+            if bw > cgp_sim.default_block_words(W, n_o, moments):
+                with pytest.raises(ValueError, match="pass 2"):
+                    call()
+                continue
+            out = [np.asarray(a) for a in call()]
+            _assert_exact(out, genomes, spec, gold, moments)
+            ints = [np.delete(out[0], FLOAT_SUMS, axis=1)] + out[1:]
+            if first is None:
+                first = ints, out[0][:, FLOAT_SUMS]
+                continue
+            for got, want in zip(ints, first[0]):
+                np.testing.assert_array_equal(got, want, err_msg=(bw, layout))
+            # float32 sums of 2^16 terms, in another order
+            np.testing.assert_allclose(out[0][:, FLOAT_SUMS], first[1],
+                                       rtol=1e-4)
+
+
+def test_block_follows_the_cube_and_the_carry():
+    """The default block is the largest the digit bound allows: the whole
+    2,048-word width-8 cube, which needs no carry, as (16, 128); 1,024
+    words (8, 128) where the kernel carries; 512 (4, 128) with the
+    moments.  A 2,048-word block is refused where the kernel carries."""
+    assert cgp_sim.default_block_words(2048, 16, False) == 2048
+    assert cgp_sim.default_block_words(1 << 19, 24, False) == 1024
+    assert cgp_sim.default_block_words(2048, 16, True) == 512
+    assert cgp_sim.default_block_words(1 << 19, 24, True) == 512
+    assert cgp_sim.default_block_words(32, 10, False) == 32
+    assert [cgp_sim.wire_shape(b) for b in (32, 128, 512, 1024, 2048)] == [
+        (1, 32), (1, 128), (4, 128), (8, 128), (16, 128)]
+    spec = CGPSpec(n_i=16, n_o=16, n_n=4)
+    g = _genomes(spec, 1, seed=0)
+    call = lambda planes, gold, **kw: cgp_sim.cgp_sim_metrics_batched(
+        g.nodes, g.outs, planes, gold, n_i=16, n_n=4, n_o=16,
+        block_words=2048, interpret=True, **kw)
+    planes = S.input_planes(16)
+    gold = jnp.asarray(G.golden_values(8, "mul"))
+    assert int(call(planes, gold)[0][0, cgp_sim.COUNT]) == 1 << 16
+    with pytest.raises(ValueError, match="block_words=2048"):
+        call(planes, gold, moments=True)
+    wide = jnp.zeros((16, 8192), jnp.int32)  # a width-9 cube: it carries
+    with pytest.raises(ValueError, match="block_words=2048"):
+        call(wide, jnp.zeros(8192 * 32, jnp.int32))
 
 
 def test_kernel_refuses_what_it_cannot_hold_exactly():

@@ -76,8 +76,8 @@ def _shape(shape, dtype, sharding):
 def test_cgp_kernel_compiles_at_paper_shape(one_chip, layout):
     i32 = lambda *s: _shape(s, jnp.int32, one_chip)
     fn = jax.jit(lambda n, o, p, g: cgp_sim.cgp_sim_metrics_batched(
-        n, o, p, g, n_i=N_I, n_n=N_N, n_o=N_O, layout=layout,
-        interpret=False))
+        n, o, p, g, n_i=N_I, n_n=N_N, n_o=N_O, block_words=512,
+        layout=layout, interpret=False))
     compiled = fn.lower(i32(R, N_N, 3), i32(R, N_O), i32(N_I, W),
                         i32(W * 32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -96,6 +96,27 @@ def test_cgp_kernel_compiles_at_width_12(one_chip, moments):
         layout="genome_major", moments=moments, interpret=False))
     compiled = fn.lower(i32(R, n_n, 3), i32(R, n_o), i32(n_i, w12),
                         i32(w12 * 32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width,n_n,moments,block", [
+    (WIDTH, N_N, False, 2048),  # the whole cube a block, (16, 128)
+    (WIDTH, N_N, True, 512),    # the moments carry: (4, 128)
+    (12, 900, False, 1024),     # the kernel carries: (8, 128)
+    (12, 900, True, 512),
+])
+def test_cgp_kernel_compiles_dense_default_blocks(one_chip, width, n_n,
+                                                  moments, block):
+    """The blocks the kernel takes by default, each wire sublane-dense."""
+    n_i = n_o = 2 * width
+    w = (1 << n_i) // 32
+    assert cgp_sim.default_block_words(w, n_o, moments) == block
+    i32 = lambda *s: _shape(s, jnp.int32, one_chip)
+    fn = jax.jit(lambda n, o, p, g: cgp_sim.cgp_sim_metrics_batched(
+        n, o, p, g, n_i=n_i, n_n=n_n, n_o=n_o, moments=moments,
+        interpret=False))
+    compiled = fn.lower(i32(R, n_n, 3), i32(R, n_o), i32(n_i, w),
+                        i32(w * 32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
