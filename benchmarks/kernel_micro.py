@@ -1,22 +1,18 @@
 """Kernel microbenchmarks: fused sim+metrics throughput (the paper's hot
 loop), the unfused baseline, the batched constraint-grid sweep engine
-vs the serial per-run loop (with a backend × Pallas-layout axis), and the
-streaming results layer (shard spill + read-back rows/s), on this host
-(CPU: jnp path; the Pallas kernel is timed in interpret mode only for
-reference — its target is TPU, and interpret mode hides the HBM cube
-traffic the cube-major layout removes).
+vs the serial per-run loop (with a backend axis), and the streaming
+results layer (shard spill + read-back rows/s), on this host (CPU: jnp
+path; the Pallas kernel is timed in interpret mode only for reference — its
+target is TPU).
 
 Script / module mode (CWD-independent):
   python -m benchmarks.kernel_micro \
       [--only eval,gen,pallas,sweep,results,certify,lut]
-      [--backend jnp,pallas] [--layout genome_major,cube_major]
-      [--smoke] [--json BENCH_out.json]
+      [--backend jnp,pallas] [--smoke] [--json BENCH_out.json]
 
 ``--smoke`` shrinks every budget to the CI bench-gate size (the
 ``bench-smoke`` job / ``make bench-check``); ``--json`` writes the metric
-dict consumed by ``tools/check_bench.py``.  ``--tune`` runs the measured
-kernel-layout autotune pass instead of the benches and refreshes the
-tuning table behind ``layout="auto"`` (``repro.kernels.tune``).
+dict consumed by ``tools/check_bench.py``.
 """
 from __future__ import annotations
 
@@ -119,23 +115,19 @@ def bench_generation_rate(width: int = 8, gens: int = 100, lam: int = 8,
 
 def bench_sweep(width: int = 3, gens: int = 200, lam: int = 4,
                 n_seeds: int = 2, backends: tuple = ("jnp", "pallas"),
-                layouts: tuple = ("genome_major", "cube_major"),
                 dedup_width: int = 6, dedup_gens: int = 60,
                 dedup_n_n: int = 300, dedup_mutation_rate: float = 0.0005,
                 sampled_width: int = 12, sampled_gens: int = 20,
                 sampled_size: int = 1 << 13):
     """Constraint-grid throughput (runs/s): batched engine vs serial loop,
-    with a ``backend`` axis over the candidate-evaluation path and — for
-    the pallas backend — a ``layout`` axis over the evaluation-grid order
-    (genome-major vs the transposed cube-major grid, DESIGN.md §7).
+    with a ``backend`` axis over the candidate-evaluation path.
 
     The grid is 6 constraint configs × ``n_seeds`` seeds; all paths are
     compiled before timing, so the ratios isolate execution throughput (the
     batched engine additionally saves one trace per seed on the cold path).
     The "pallas" legs drive the fused (runs × λ) kernel — on CPU it runs in
     interpret mode, so their runs/s are correctness-path references; the
-    jnp-vs-pallas and layout gaps worth tracking are on a TPU backend
-    (interpret mode hides the HBM reuse cube-major buys).
+    jnp-vs-pallas gap worth tracking is on a TPU backend.
 
     The ``dedup_*`` legs time the phenotype-dedup cache (DESIGN.md §8) on a
     deliberately neutral-mutation-heavy grid — wide cube, big genome, low
@@ -179,25 +171,15 @@ def bench_sweep(width: int = 3, gens: int = 200, lam: int = 4,
     t_serial = time.perf_counter() - t0
     out = {"n_runs": n_runs, "serial_runs_per_s": n_runs / t_serial}
 
-    def one(backend, layout=None, tag=None):
+    for backend in backends:
         cfg_b = dataclasses.replace(
             cfg, evolve=dataclasses.replace(cfg.evolve, backend=backend))
-        sweep_b = sweep if layout is None else dataclasses.replace(
-            sweep, layout=layout)
-        run_sweep_batched(cfg_b, cons, seeds, sweep_b)  # compile
+        run_sweep_batched(cfg_b, cons, seeds, sweep)  # compile
         t0 = time.perf_counter()
-        run_sweep_batched(cfg_b, cons, seeds, sweep_b)
+        run_sweep_batched(cfg_b, cons, seeds, sweep)
         t_b = time.perf_counter() - t0
-        tag = tag or backend
-        out[f"batched_{tag}_runs_per_s"] = n_runs / t_b
-        out[f"batched_{tag}_speedup"] = t_serial / t_b
-
-    for backend in backends:
-        if backend == "pallas":
-            for layout in layouts:  # layout is a no-op on the jnp path
-                one(backend, layout, tag=f"pallas_{layout}")
-        else:
-            one(backend)
+        out[f"batched_{backend}_runs_per_s"] = n_runs / t_b
+        out[f"batched_{backend}_speedup"] = t_serial / t_b
 
     # --- phenotype-dedup legs (DESIGN.md §8): neutral-mutation-heavy grid --
     dcfg = SearchConfig(
@@ -433,34 +415,6 @@ SMOKE = {
 }
 
 
-def run_tune(widths, runs, reps, n_n=400, table=None):
-    """``--tune`` mode: measured autotune pass over a (width × R) grid —
-    emits/refreshes the tuning table behind ``layout="auto"``.
-
-    ``n_n`` defaults to the paper/production genome size: table keys carry
-    only (width, R, backend), so entries must be measured at the node count
-    they will decide for — the genome-block re-fetch cost cube-major pays
-    scales with n_n (DESIGN.md §7.1), and a small-genome winner could pin
-    the losing layout for 400-node sweeps.
-    """
-    from repro.kernels import tune
-
-    def time_fn(fn, reps):  # the bench harness timer, per the tune contract
-        return _time(fn, reps=reps)
-
-    path = table or tune.table_path()
-    for width in widths:
-        for R in runs:
-            entry = tune.autotune(width, R, n_n=n_n, reps=reps, path=path,
-                                  time_fn=time_fn)
-            secs = ", ".join(f"{k}={v:.4g}s" for k, v in
-                             entry["seconds"].items())
-            print(f"[tune] w{width} R{R} {entry['backend']}: winner "
-                  f"{entry['layout']}/bw{entry['block_words']}"
-                  f"/rt{entry['r_tile']}  ({secs})", flush=True)
-    print(f"[tune] table -> {path}", flush=True)
-
-
 def main(argv=None):
     import argparse
     import functools
@@ -472,49 +426,20 @@ def main(argv=None):
     ap.add_argument("--backend", default="jnp,pallas",
                     help="comma list of sweep-engine backends to time "
                          "(--only sweep axis; default: jnp,pallas)")
-    ap.add_argument("--layout", default="genome_major,cube_major",
-                    help="comma list of Pallas evaluation-grid layouts for "
-                         "the pallas sweep legs (default: both; DESIGN.md "
-                         "section 7)")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI budget for every bench (the bench-smoke "
                          "job / make bench-check)")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write results as JSON (the BENCH_<sha>.json "
                          "consumed by tools/check_bench.py)")
-    ap.add_argument("--tune", action="store_true",
-                    help="run the measured kernel-layout autotune pass "
-                         "instead of the benches (repro.kernels.tune; "
-                         "refreshes the table behind layout='auto')")
-    ap.add_argument("--tune-widths", default="2,4",
-                    help="--tune: comma list of circuit widths")
-    ap.add_argument("--tune-runs", default="8,32",
-                    help="--tune: comma list of population sizes R")
-    ap.add_argument("--tune-reps", type=int, default=3,
-                    help="--tune: timed reps per variant")
-    ap.add_argument("--tune-nodes", type=int, default=400,
-                    help="--tune: genome node count to measure at (keep the "
-                         "production shape: table keys omit n_n)")
-    ap.add_argument("--tune-table", default=None,
-                    help="--tune: tuning-table path override "
-                         "(default: REPRO_TUNE_TABLE or the repo table)")
     args = ap.parse_args(argv)
-    if args.tune:
-        run_tune([int(w) for w in args.tune_widths.split(",") if w],
-                 [int(r) for r in args.tune_runs.split(",") if r],
-                 args.tune_reps, args.tune_nodes, args.tune_table)
-        return
     only = set(args.only.split(",")) if args.only else None
     backends = tuple(b for b in args.backend.split(",") if b)
     if unknown := set(backends) - {"jnp", "pallas"}:
         ap.error(f"unknown backend(s): {sorted(unknown)}")
-    layouts = tuple(l for l in args.layout.split(",") if l)
-    if unknown := set(layouts) - {"genome_major", "cube_major"}:
-        ap.error(f"unknown layout(s): {sorted(unknown)}")
     benches = {"eval": bench_eval_throughput, "gen": bench_generation_rate,
                "pallas": bench_pallas_interpret,
-               "sweep": functools.partial(bench_sweep, backends=backends,
-                                          layouts=layouts),
+               "sweep": functools.partial(bench_sweep, backends=backends),
                "results": bench_results,
                "certify": bench_certify,
                "lut": bench_lut}
@@ -532,8 +457,7 @@ def main(argv=None):
         parts = ", ".join(f"{k}={v:.4g}" for k, v in out.items())
         print(f"[{name}] {parts}", flush=True)
     if args.json:
-        results["_meta"] = {"smoke": args.smoke, "backends": list(backends),
-                            "layouts": list(layouts)}
+        results["_meta"] = {"smoke": args.smoke, "backends": list(backends)}
         with open(args.json, "w") as f:
             json.dump(results, f, indent=1, sort_keys=True)
         print(f"[json] -> {args.json}", flush=True)

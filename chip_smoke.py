@@ -1,29 +1,22 @@
 #!/usr/bin/env python3
-"""Smoke run of the constraint sweep on a TPU: the main path, compiled.
+"""Smoke run of the constraint sweep on a TPU chip: the main path, compiled.
 
-  python chip_smoke.py              # one chip
-  python chip_smoke.py --chips 4    # cube-sharded sweep over four local chips
+  python chip_smoke.py
 
-One chip: the paper's problem at full width — an 8x8 multiplier, 400 CGP
-nodes, the exhaustive 2^16 input cube — swept over 2 constraints x 16 seeds
-with lambda=8 through ``repro.launch.evolve.main``: one chunk of 32 runs, so
+The paper's problem at full width — an 8x8 multiplier, 400 CGP nodes, the
+exhaustive 2^16 input cube — swept over 2 constraints x 16 seeds with
+lambda=8 through ``repro.launch.evolve.main``: one chunk of 32 runs, so
 every generation evaluates 256 candidates in one kernel dispatch, streams
-result shards and exports LUT artifacts.  The sweep runs three times — the
-Pallas kernel in each grid layout and the pure-jnp backend — and the three
-must agree: genomes and every metric exactly, MRE (a float32 sum of
-quotients) to a relative 1e-4, and the other float32 reductions (power,
-hence fitness, and the mean and spread of the signed error) exactly between
-the layouts and to a relative 1e-5 between different programs.  The
-constraints leave MRE unconstrained, so no trajectory can fork on it.  Each
-artifact registry then passes ``export --verify``, and the lowered Pallas
-step must hold a ``tpu_custom_call`` (the kernel compiled, not interpreted).
+result shards and exports LUT artifacts.  The sweep runs twice — the Pallas
+kernel and the pure-jnp backend — and the two must agree: genomes and every
+metric exactly, MRE (a float32 sum of quotients) to a relative 1e-4, and
+the other float32 reductions (power, hence fitness, and the mean and spread
+of the signed error) to a relative 1e-5.  The constraints leave MRE
+unconstrained, so no trajectory can fork on it.  Each artifact registry
+then passes ``export --verify``, and the lowered Pallas step must hold a
+``tpu_custom_call`` (the kernel compiled, not interpreted).
 
-``--chips 4`` runs only the same grid with the input cube sharded over all
-local chips (``make_sweep_mesh(pods=1)`` + ``SweepConfig(model_axis=
-"model")``) and compares it with the same sweep on one chip, run in this
-process.
-
-Everything runs in this one process: it holds the chip(s) and starts no
+Everything runs in this one process: it holds the chip and starts no
 child.  It exits non-zero without a TPU, and on any failed phase.  The
 seconds and rates it prints are smoke figures, not benchmark numbers.  The
 last line of stdout is the verdict:
@@ -62,11 +55,11 @@ def check(ok: bool, msg: str) -> None:
         raise RuntimeError(msg)
 
 
-def evolve_argv(backend: str, layout: str, out: str) -> list:
+def evolve_argv(backend: str, out: str) -> list:
     argv = ["--width", str(WIDTH), "--kind", "mul", "--nodes", str(NODES),
             "--generations", str(GENS), "--lam", str(LAM),
             "--seeds", str(SEEDS), "--chunk-size", str(CHUNK),
-            "--backend", backend, "--layout", layout,
+            "--backend", backend,
             "--results-dir", os.path.join(out, "shards"),
             "--history", "summary",
             "--export-artifacts", os.path.join(out, "registry")]
@@ -75,12 +68,12 @@ def evolve_argv(backend: str, layout: str, out: str) -> list:
     return argv
 
 
-def search_config(backend: str, layout: str):
+def search_config(backend: str):
     from repro.core.evolve import EvolveConfig
     from repro.core.search import SearchConfig
     return SearchConfig(width=WIDTH, kind="mul", n_n=NODES,
                         evolve=EvolveConfig(generations=GENS, lam=LAM,
-                                            backend=backend, layout=layout))
+                                            backend=backend))
 
 
 def chunk_args(cfg):
@@ -111,16 +104,14 @@ def compile_chunk(fn, *args):
     return time.perf_counter() - t0, compiled
 
 
-def compare(ref_dir: str, got_dir: str, label: str,
-            same_program: bool) -> None:
+def compare(ref_dir: str, got_dir: str, label: str) -> None:
     """Two shard sets of the same grid: genomes, metrics and per-generation
     histories equal; the MRE metric column to MRE_RTOL.
 
     Power (hence fitness) and the signed-error moments are float32
     reductions that XLA may reassociate differently in two different
-    programs; unless both runs compiled the same program (``same_program``),
-    those columns (FLOAT_FIELDS) are held to FLOAT_RTOL.  Equal genomes still
-    show that no trajectory forked on them.
+    programs, so those columns (FLOAT_FIELDS) are held to FLOAT_RTOL.
+    Equal genomes still show that no trajectory forked on them.
     """
     import numpy as np
     from repro.core import metrics as M
@@ -139,7 +130,7 @@ def compare(ref_dir: str, got_dir: str, label: str,
                   f"{label}: {what} differ")
             a, b, rtol, what = a[..., M.MRE], b[..., M.MRE], MRE_RTOL, \
                 f"{what} MRE"
-        elif what in FLOAT_FIELDS and not same_program:
+        elif what in FLOAT_FIELDS:
             rtol = FLOAT_RTOL
         else:
             check(np.array_equal(a, b), f"{label}: {what} differ")
@@ -167,7 +158,7 @@ def smoke_figures(label: str, compile_s: float, wall_s: float) -> None:
         f"{runs * GENS * LAM / wall_s:.1f} evaluations/s")
 
 
-def run_evolve(backend: str, layout: str, out: str) -> float:
+def run_evolve(backend: str, out: str) -> float:
     """``launch.evolve.main`` into ``out``; its stdout goes to evolve.log
     (the [evolve] status lines are echoed).  Returns wall seconds."""
     from repro.launch import evolve
@@ -176,7 +167,7 @@ def run_evolve(backend: str, layout: str, out: str) -> float:
     buf = io.StringIO()
     t0 = time.perf_counter()
     with contextlib.redirect_stdout(buf):
-        evolve.main(evolve_argv(backend, layout, out))
+        evolve.main(evolve_argv(backend, out))
     wall = time.perf_counter() - t0
     with open(os.path.join(out, "evolve.log"), "w") as f:
         f.write(buf.getvalue())
@@ -188,105 +179,35 @@ def run_evolve(backend: str, layout: str, out: str) -> float:
 
 def one_chip(out: str) -> None:
     from repro.core import sweep
-    from repro.kernels import ops, tune
+    from repro.kernels import ops
     from repro.launch import export
 
     check(not ops.default_interpret(), "Pallas interpret mode is on")
-    auto = tune.resolve_variant(WIDTH, CHUNK * LAM, tune.backend_key(False))
-    log(f"layout=auto would run {auto.key()} at R={CHUNK * LAM}; this run "
-        f"names its layouts explicitly")
-
-    runs = (("pallas", "genome_major"), ("pallas", "cube_major"),
-            ("jnp", "genome_major"))
     dirs = {}
-    for backend, layout in runs:
-        label = f"{backend}/{layout}" if backend == "pallas" else backend
-        cfg = search_config(backend, layout)
+    for backend in ("pallas", "jnp"):
+        cfg = search_config(backend)
         compile_s, compiled = compile_chunk(sweep.evolve_chunk,
                                             *chunk_args(cfg))
         has_kernel = "tpu_custom_call" in compiled.as_text()
         check(has_kernel == (backend == "pallas"),
-              f"{label}: tpu_custom_call in the chunk program: {has_kernel}")
+              f"{backend}: tpu_custom_call in the chunk program: "
+              f"{has_kernel}")
         if backend == "pallas":
-            log(f"{label}: chunk program holds the compiled Mosaic kernel "
+            log(f"{backend}: chunk program holds the compiled Mosaic kernel "
                 f"(tpu_custom_call)")
-        dirs[label] = os.path.join(out, label.replace("/", "_"))
-        wall_s = run_evolve(backend, layout, dirs[label])
-        smoke_figures(label, compile_s, wall_s)
+        dirs[backend] = os.path.join(out, backend)
+        wall_s = run_evolve(backend, dirs[backend])
+        smoke_figures(backend, compile_s, wall_s)
         check(export.main(["--verify",
-                           os.path.join(dirs[label], "registry")]) == 0,
-              f"{label}: export --verify failed")
+                           os.path.join(dirs[backend], "registry")]) == 0,
+              f"{backend}: export --verify failed")
 
-    shards = {k: os.path.join(v, "shards") for k, v in dirs.items()}
-    compare(shards["pallas/genome_major"], shards["pallas/cube_major"],
-            "pallas genome_major vs cube_major", same_program=True)
-    compare(shards["jnp"], shards["pallas/genome_major"],
-            "jnp vs pallas genome_major", same_program=False)
-
-
-def four_chips(out: str) -> None:
-    from repro.core import sweep
-    from repro.core.sweep import SweepConfig, run_sweep_batched
-    from repro.launch.evolve import parse_constraint
-    from repro.launch.mesh import make_sweep_mesh
-    from repro.parallel import ctx
-
-    cfg = search_config("pallas", "auto")
-    cons = [parse_constraint(c) for c in CONSTRAINTS]
-    mesh = make_sweep_mesh(pods=1)
-    n_model = mesh.shape["model"]
-    check(n_model == 4, f"mesh {dict(mesh.shape)} has no 4-way model axis")
-
-    def sweep_into(name: str, model_axis: str | None) -> float:
-        """The sweep twice, into ``name/first`` and ``name/warm``: the first
-        compiles (or loads) its programs, the warm one reuses them.  Logs
-        the first wall and returns the warm one."""
-        walls = []
-        for run in ("first", "warm"):
-            t0 = time.perf_counter()
-            res = run_sweep_batched(cfg, cons, range(SEEDS), SweepConfig(
-                chunk_size=CHUNK, results_dir=os.path.join(out, name, run),
-                keep_history="summary", model_axis=model_axis))
-            walls.append(time.perf_counter() - t0)
-            check(res.completed == res.n_runs, f"{name}: incomplete sweep")
-        log(f"{name}: first sweep wall {walls[0]:.2f} s (with its compile)")
-        return walls[1]
-
-    args = chunk_args(cfg)
-    compile_1, _ = compile_chunk(sweep.evolve_chunk, *args)
-    wall_1 = sweep_into("one_chip", None)
-    smoke_figures("pallas on one chip", compile_1, wall_1)
-
-    spec, ecfg, gold, thr, planes, gvals, gpower, keys = args
-    fn = sweep._sharded_chunk_fn(mesh, "model", spec, ecfg)
-    compile_4, compiled = compile_chunk(fn, gold.nodes, gold.outs, thr,
-                                        planes, gvals, gpower, keys)
-    text = compiled.as_text()
-    check("tpu_custom_call" in text, "sharded chunk: no tpu_custom_call")
-    check("all-reduce" in text, "sharded chunk: no all-reduce of partials")
-    sharding = compiled.input_shardings[0][3]  # in_planes
-    slices = {(d.id, idx[1].start, idx[1].stop) for d, idx in
-              sharding.devices_indices_map(planes.shape).items()}
-    check(len(slices) == n_model and
-          len({s[1] for s in slices}) == n_model,
-          f"cube shards per device: {sorted(slices)}")
-    log(f"cube shards: word slices {sorted((s[1], s[2]) for s in slices)} "
-        f"on devices {sorted(s[0] for s in slices)}")
-    with ctx.use_mesh(mesh):
-        wall_4 = sweep_into("four_chips", "model")
-    smoke_figures(f"pallas cube-sharded over {n_model} chips", compile_4,
-                  wall_4)
-    compare(os.path.join(out, "one_chip", "warm"),
-            os.path.join(out, "four_chips", "warm"),
-            "one chip vs cube-sharded", same_program=False)
+    compare(os.path.join(dirs["jnp"], "shards"),
+            os.path.join(dirs["pallas"], "shards"), "jnp vs pallas")
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
-                    help="1: both Pallas layouts and jnp on one chip; 4: "
-                         "only the cube-sharded sweep over four chips, "
-                         "compared with one chip")
     ap.add_argument("--out", default=os.path.join(
         REPO, "experiments", "tmp", "chip_smoke"),
         help="output directory (emptied first)")
@@ -304,19 +225,13 @@ def main(argv=None) -> int:
     if dev["platform"] != "tpu":
         log("FAIL: no TPU found; this script never falls back to the CPU")
         return 2
-    if dev["count"] < args.chips:
-        log(f"FAIL: --chips {args.chips} but {dev['count']} device(s)")
-        return 2
 
     sys.path.insert(0, os.path.join(REPO, "src"))
     from repro.runtime.compile_cache import enable_compile_cache
     log(f"compile cache: {enable_compile_cache()}")
     shutil.rmtree(args.out, ignore_errors=True)
     os.makedirs(args.out)
-    if args.chips == 4:
-        four_chips(args.out)
-    else:
-        one_chip(args.out)
+    one_chip(args.out)
     print(json.dumps({"ok": True, "device": dev}), flush=True)
     return 0
 

@@ -8,9 +8,10 @@ Single-island semantics (paper-faithful):
 
 Distributed semantics (DESIGN.md §2 — the TPU-cluster formulation):
   mesh axes  pod  × data × model
-             │       │       └─ input-space sharding: each shard simulates a
-             │       │          2^n_i/axis slice of the cube; metric partials
-             │       │          and signal-prob popcounts combine with psum.
+             │       │       └─ input-space sharding (jnp backend): each
+             │       │          shard simulates a 2^n_i/axis slice of the
+             │       │          cube; metric partials and signal-prob
+             │       │          popcounts combine with psum.
              │       └─ islands: independent (1+λ) runs; every
              │          ``migrate_every`` generations the globally best parent
              │          is broadcast and replaces strictly-worse parents.
@@ -54,33 +55,27 @@ class EvolveConfig:
     gauss_sigma: float = 256.0
     seed: int = 0
     backend: str = "jnp"         # "jnp" | "pallas" candidate evaluation
-    # Pallas evaluation-grid order (DESIGN.md §7): "genome_major",
-    # "cube_major", or "auto" (tuning-table resolution via kernels.tune).
-    # Pure execution knob — results are bit-identical across layouts, so it
-    # is deliberately NOT part of the sweep grid fingerprint (checkpoints /
-    # result shards resume across layout changes).  Ignored by backend="jnp".
-    layout: str = "auto"
+    # Read by nothing: the kernel has one grid order (DESIGN.md §7).
+    layout: str = "genome_major"
     # Phenotype-dedup evaluation cache (DESIGN.md §8): the batched sweep
     # engine canonicalizes+hashes each offspring's active subgraph, skips
     # the kernel for phenotypes already seen (within the generation or in
-    # the cross-generation LRU) and scatters the cached result back.  Like
-    # ``layout`` this is a pure execution knob — results are bit-identical
-    # with the cache on or off, so it is NOT part of the grid fingerprint
-    # and checkpoints/shards resume across the setting.  Honored by
-    # ``core.sweep.run_sweep_batched`` (the serial ``evolve`` path and
-    # model-axis-sharded dispatches ignore it).
+    # the cross-generation LRU) and scatters the cached result back.  A
+    # pure execution knob — results are bit-identical with the cache on or
+    # off, so it is NOT part of the grid fingerprint and checkpoints/shards
+    # resume across the setting.  Honored by ``core.sweep.run_sweep_batched``
+    # (the serial ``evolve`` path ignores it).
     dedup: bool = False
     # Evaluation-input mode (DESIGN.md §9).  "exhaustive" scores candidates
     # on the full 2^(2w) input cube (the historic default — bit-identical to
     # the pre-§9 engine); "sampled" scores them on a deterministic
     # ``sample_size``-row operand sample drawn from ``input_dist`` with the
     # counter-based stream seeded by ``sample_seed`` (``core.sampling``).
-    # UNLIKE layout/dedup this is result-changing: it IS part of the sweep
-    # grid fingerprint and of the dedup cache scope (via the sample-stream
+    # UNLIKE dedup this is result-changing: it IS part of the sweep grid
+    # fingerprint and of the dedup cache scope (via the sample-stream
     # fingerprint).  The evolve/sweep engine itself only consumes whatever
     # (in_planes, golden_vals) it is handed — the mode picks which pair
-    # ``search.problem_arrays`` builds, so sample shards reuse the cube-shard
-    # psum/pmax contract unchanged.
+    # ``search.problem_arrays`` builds.
     eval_mode: str = "exhaustive"    # "exhaustive" | "sampled"
     sample_size: int = 1 << 14       # rows (rounded up to pow2 words * 32)
     input_dist: str = "uniform"      # "uniform" | "gaussian" | "empirical"
@@ -100,6 +95,10 @@ class EvolveConfig:
     certify_budget: int = 8          # base escalations per sweep chunk
 
     def __post_init__(self):
+        # bench/tests/test_compile.py still sets layout="genome_major"
+        if self.layout != "genome_major":
+            raise ValueError(f"layout must be 'genome_major', got "
+                             f"{self.layout!r}")
         if self.eval_mode not in ("exhaustive", "sampled"):
             raise ValueError(f"eval_mode must be 'exhaustive' or 'sampled', "
                              f"got {self.eval_mode!r}")
@@ -167,12 +166,13 @@ def _eval_jnp(genome: Genome, spec: CGPSpec, in_planes: jax.Array,
 def _eval_pallas(genome: Genome, spec: CGPSpec, in_planes: jax.Array,
                  golden_vals: jax.Array, gauss_sigma: float,
                  axis_name: str | None) -> EvalResult:
-    """Fused Pallas sim+metrics kernel path (interpret=True on CPU)."""
+    """Fused Pallas sim+metrics kernel path (interpret=True on CPU), over
+    the whole cube: a sharded cube (``axis_name``) is refused."""
+    if axis_name is not None:
+        raise ValueError("the Pallas kernel evaluates the whole cube on one "
+                         f"chip; got a cube sharded over {axis_name!r}")
     partials, pop = kops.cgp_eval(genome, spec, in_planes, golden_vals,
                                   gauss_sigma)
-    if axis_name is not None:
-        partials = M.combine_partials(partials, axis_name)
-        pop = jax.lax.psum(pop, axis_name)
     n_total = partials.count.astype(jnp.float32)
     probs = pop / n_total
     metric_vec = M.finalize_metrics(partials, spec.n_o, gauss_sigma)
@@ -186,32 +186,28 @@ def get_eval_fn(backend: str) -> Callable[..., EvalResult]:
 
 def _eval_pop_jnp(genomes: Genome, spec: CGPSpec, in_planes: jax.Array,
                   golden_vals: jax.Array, gauss_sigma: float,
-                  axis_name: str | None, layout: str = "auto") -> EvalResult:
-    """Population (leading-R) evaluation: vmap of the per-genome jnp path.
-    ``layout`` is a Pallas-grid knob and is ignored here."""
+                  axis_name: str | None) -> EvalResult:
+    """Population (leading-R) evaluation: vmap of the per-genome jnp path;
+    ``axis_name`` shards the cube (partials psum across it)."""
     return jax.vmap(lambda g: _eval_jnp(g, spec, in_planes, golden_vals,
                                         gauss_sigma, axis_name))(genomes)
 
 
 def _eval_pop_pallas(genomes: Genome, spec: CGPSpec, in_planes: jax.Array,
                      golden_vals: jax.Array, gauss_sigma: float,
-                     axis_name: str | None, layout: str = "auto"
-                     ) -> EvalResult:
+                     axis_name: str | None) -> EvalResult:
     """Population evaluation as ONE fused kernel dispatch.
 
     The stacked genome axis lands on the Pallas grid instead of a vmap
-    batching dimension (``kernels.ops.cgp_eval_batched``); ``layout`` picks
-    the grid order — genome-major or the transposed cube-major grid
-    (DESIGN.md §7), bit-identical results either way.  Input-space sharding
-    (``axis_name``) stays fused: each shard dispatches the same grid on its
-    cube slice and the per-genome accumulators psum/pmax across the axis
-    inside the kernel wrapper (the cube-shard variant, DESIGN.md §6) — the
-    partials and popcounts coming back are already cube-global.
+    batching dimension (``kernels.ops.cgp_eval_batched``), and the kernel
+    evaluates the whole cube on one chip (DESIGN.md §7): a sharded cube
+    (``axis_name``) is refused.
     """
+    if axis_name is not None:
+        raise ValueError("the Pallas kernel evaluates the whole cube on one "
+                         f"chip; got a cube sharded over {axis_name!r}")
     partials, pops = kops.cgp_eval_batched(genomes, spec, in_planes,
-                                           golden_vals, gauss_sigma,
-                                           axis_name=axis_name,
-                                           layout=layout)
+                                           golden_vals, gauss_sigma)
     n_total = partials.count.astype(jnp.float32)            # (R,)
     probs = pops / n_total[:, None]
     metric_vec = jax.vmap(
@@ -263,7 +259,7 @@ def make_generation_step(spec: CGPSpec, cfg: EvolveConfig,
         offspring = mutate_population(k_mut, state.parent, spec, cfg.lam,
                                       cfg.mutation_rate)
         res = eval_pop(offspring, spec, in_planes, golden_vals,
-                       cfg.gauss_sigma, axis_name, cfg.layout)
+                       cfg.gauss_sigma, axis_name)
         fits = jax.vmap(fitness_fn)(res.cost.power,
                                     res.metric_vec,
                                     jnp.broadcast_to(thresholds,
@@ -307,8 +303,7 @@ def init_state(spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
                        golden, fit, key)
 
 
-def make_batched_generation_step(spec: CGPSpec, cfg: EvolveConfig,
-                                 axis_name: str | None = None):
+def make_batched_generation_step(spec: CGPSpec, cfg: EvolveConfig):
     """Run-batched one-generation function for the batched sweep engine.
 
     ``state`` leaves and ``thr_mat`` carry a leading run axis C.  Mutation
@@ -319,13 +314,6 @@ def make_batched_generation_step(spec: CGPSpec, cfg: EvolveConfig,
     R = C·λ genomes on the grid, instead of a vmap-of-vmap-of-pallas_call.
     Same positional signature as ``make_generation_step``'s result, so it
     drops into ``scan_generations`` directly.
-
-    ``axis_name`` enables input-space sharding of the fused dispatch
-    (DESIGN.md §6): ``in_planes``/``golden_vals`` are this shard's cube
-    slice and the evaluation partials combine across the axis, so the whole
-    (C × λ) population still evaluates as one (sharded) dispatch per
-    generation.  Mutation/selection run on per-run state that shard_map
-    replicates, so the step must execute under a context binding the axis.
     """
     eval_pop = get_population_eval(cfg.backend)
 
@@ -340,7 +328,7 @@ def make_batched_generation_step(spec: CGPSpec, cfg: EvolveConfig,
         flat = jax.tree.map(
             lambda x: x.reshape((C * cfg.lam,) + x.shape[2:]), offspring)
         res = eval_pop(flat, spec, in_planes, golden_vals, cfg.gauss_sigma,
-                       axis_name, cfg.layout)
+                       None)
         res = jax.tree.map(
             lambda x: x.reshape((C, cfg.lam) + x.shape[1:]), res)
         fits = jax.vmap(lambda p, m, t: jax.vmap(fitness_fn)(
@@ -354,15 +342,14 @@ def make_batched_generation_step(spec: CGPSpec, cfg: EvolveConfig,
 
 def init_state_batched(spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
                        thr_mat: jax.Array, in_planes: jax.Array,
-                       golden_vals: jax.Array, keys: jax.Array,
-                       axis_name: str | None = None) -> EvolveState:
+                       golden_vals: jax.Array, keys: jax.Array
+                       ) -> EvolveState:
     """Per-run init for the batched sweep: the golden parent is evaluated
     ONCE (it is identical for every run) and broadcast over the run axis;
-    only fitness differs per run (per-run thresholds).  ``axis_name`` shards
-    the golden evaluation over the cube like the generation step's."""
+    only fitness differs per run (per-run thresholds)."""
     eval_fn = get_eval_fn(cfg.backend)
     res = eval_fn(golden, spec, in_planes, golden_vals, cfg.gauss_sigma,
-                  axis_name)
+                  None)
     C = thr_mat.shape[0]
     fit = jax.vmap(
         lambda t: fitness_fn(res.cost.power, res.metric_vec, t))(thr_mat)
@@ -413,7 +400,7 @@ def eval_segment(spec: CGPSpec, cfg: EvolveConfig, nodes: jax.Array,
     """
     res = get_population_eval(cfg.backend)(
         Genome(nodes, outs), spec, in_planes, golden_vals, cfg.gauss_sigma,
-        None, cfg.layout)
+        None)
     return res.metric_vec, res.cost.power
 
 
@@ -478,24 +465,26 @@ def evolve(spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
 
 def evolve_sharded(mesh, spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
                    thresholds_per_pod: jax.Array, golden_power: jax.Array,
-                   *, data_axis: str = "data", model_axis: str = "model",
+                   *, data_axis: str = "data", cube_axis: str = "model",
                    pod_axis: str | None = None):
     """Build the shard_map'd multi-island evolve function (DESIGN.md §2.2).
 
     This is the ISLAND formulation of the distributed search: one (1+λ) run
     per ``data``-axis slice with periodic best-parent migration
     (``_migrate``), each run's candidate evaluation input-space-sharded over
-    ``model`` (metric partials / popcounts psum across it, see
-    ``metrics.combine_partials``), and — when ``pod_axis`` is given — one
-    constraint configuration per pod slice.  For the paper's constraint×seed
-    GRID at production scale, use the pod-sharded batched sweep instead
-    (``core.sweep.run_sweep_batched`` with ``SweepConfig.n_pods``, DESIGN.md
-    §6): there the pod axis partitions whole chunks of independent runs and
-    needs no cross-pod collectives at all.
+    ``cube_axis`` (metric partials / popcounts psum across it, see
+    ``metrics.combine_partials``; jnp backend only, as the Pallas kernel
+    evaluates the whole cube on one chip), and — when ``pod_axis`` is given
+    — one constraint configuration per pod slice.  For the paper's
+    constraint×seed GRID at production scale, use the pod-sharded batched
+    sweep instead (``core.sweep.run_sweep_batched`` with
+    ``SweepConfig.n_pods``, DESIGN.md §6): there the pod axis partitions
+    whole chunks of independent runs and needs no cross-pod collectives at
+    all.
 
     Args:
       mesh: the active device mesh; must carry ``data_axis`` and
-        ``model_axis`` (and ``pod_axis`` when given).  The production shapes
+        ``cube_axis`` (and ``pod_axis`` when given).  The production shapes
         are built by ``launch.mesh``.
       spec/cfg/golden/golden_power: the problem, as in ``evolve`` —
         ``cfg.migrate_every`` sets the island migration period.
@@ -503,28 +492,26 @@ def evolve_sharded(mesh, spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
         sharded over ``pod_axis`` so each pod slice evolves under its own
         combined-constraint vector — or ``(1, N_METRICS)`` replicated when
         ``pod_axis`` is None (every island shares one constraint).
-      data_axis / model_axis / pod_axis: physical mesh-axis names (the
+      data_axis / cube_axis / pod_axis: physical mesh-axis names (the
         logical mapping lives in ``parallel.ctx.LOGICAL``).
 
     Returns:
       fn(thresholds, keys, in_planes, golden_vals) — shard_map'd over
       ``mesh``; ``keys`` is ``(n_islands,)`` PRNG keys sharded over
       ``data_axis`` (see ``make_island_keys``), ``in_planes``/``golden_vals``
-      the input cube sharded over ``model_axis`` on the word/value axis.
+      the input cube sharded over ``cube_axis`` on the word/value axis.
       Returns per-island stacked (parent, best, best_fit, hist_power_rel,
       hist_metrics, hist_fit), gathered over ``data_axis``.
     """
     from jax.sharding import PartitionSpec as P
 
-    axes = [a for a in (pod_axis, data_axis, model_axis) if a is not None]
-
     def island_run(thresholds, key, in_planes, golden_vals):
         # runs on ONE (pod, data, model) shard; model axis splits the cube
         thresholds = thresholds[0]  # local shard is (1, N_METRICS)
-        step = make_generation_step(spec, cfg, axis_name=model_axis,
+        step = make_generation_step(spec, cfg, axis_name=cube_axis,
                                     island_axis=data_axis)
         state0 = init_state(spec, cfg, golden, thresholds, in_planes,
-                            golden_vals, key[0], axis_name=model_axis)
+                            golden_vals, key[0], axis_name=cube_axis)
         state, (hp, hm, hf) = scan_generations(step, state0, thresholds,
                                                in_planes, golden_vals,
                                                golden_power, cfg.generations)
@@ -536,8 +523,8 @@ def evolve_sharded(mesh, spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
     pod = pod_axis if pod_axis is not None else None
     in_specs = (P(pod, None),            # thresholds (pods, N_METRICS)
                 P(data_axis),            # per-island keys
-                P(None, model_axis),     # input planes (n_i, W)
-                P(model_axis))           # golden values (2^n,)
+                P(None, cube_axis),      # input planes (n_i, W)
+                P(cube_axis))            # golden values (2^n,)
     out_leaf = P(data_axis)
     out_specs = (jax.tree.map(lambda _: out_leaf, golden),
                  jax.tree.map(lambda _: out_leaf, golden),

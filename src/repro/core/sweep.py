@@ -50,11 +50,8 @@ process executes only pod ``pod_index``'s slice — each pod dispatches its own
 Resume is per pod (each pod skips the committed prefix of its OWN span
 sequence), and because every chunk's bytes are a deterministic function of
 the fingerprinted grid, a pod-sharded sweep produces bit-identical shards to
-the single-host run of the same grid.  ``SweepConfig.model_axis``
-additionally shards each dispatch's input cube over that mesh axis
-(``shard_map`` around ``evolve_chunk``; evaluation partials psum through the
-cube-shard kernel variant), fusing pods × chunk × λ × cube-shards into one
-dispatch per generation per pod.
+the single-host run of the same grid.  Every dispatch evaluates the whole
+input cube on one chip (DESIGN.md §7).
 """
 from __future__ import annotations
 
@@ -128,32 +125,15 @@ class SweepConfig:
     only resume state whose coverage tolerates per-pod prefixes) and refuse
     ``checkpoint_dir`` (checkpoints assume one global prefix).
 
-    ``layout`` overrides the Pallas evaluation-grid order for every chunk
-    dispatch of THIS sweep (``None`` defers to ``cfg.evolve.layout``):
-    ``"genome_major"``, ``"cube_major"``, or ``"auto"`` (measured
-    tuning-table resolution, DESIGN.md §7).  A pure execution knob — runs
-    are bit-identical across layouts, the grid fingerprint ignores it, and
-    a sweep checkpointed under one layout resumes under another.
-
-    ``model_axis`` names a mesh axis of the ACTIVE ``parallel.ctx`` mesh to
-    input-space-shard every dispatch over: ``evolve_chunk`` runs under
-    ``shard_map`` with the cube's word axis split across it and evaluation
-    partials psum'd (the cube-shard kernel variant), per-run state
-    replicated.  Selection under MAE/WCE/ER/AVG/ACC0 constraints stays
-    bit-identical to the unsharded dispatch (integer-exact partials); MRE
-    sums are reassociated, so MRE-constrained runs are only allclose.
-
     ``dedup`` overrides the phenotype-dedup evaluation cache for every chunk
     of THIS sweep (``None`` defers to ``cfg.evolve.dedup``; DESIGN.md §8):
     offspring sharing an active subgraph are evaluated once per generation,
     and a cross-generation host LRU (``dedup_cache_size`` entries, keyed by
     phenotype digest × grid fingerprint × σ) skips the kernel for phenotypes
-    it has already measured.  Execution-only like ``layout`` — results are
-    bit-identical with the cache on or off, the grid fingerprint ignores it,
-    and checkpoints/shards resume across the setting.  Measured hit/miss
-    counters come back on ``SweepResult.dedup_stats``.  Incompatible with
-    ``model_axis`` (the dedup loop is host-driven; a cube-sharded dispatch
-    is one fused program).
+    it has already measured.  Execution-only — results are bit-identical
+    with the cache on or off, the grid fingerprint ignores it, and
+    checkpoints/shards resume across the setting.  Measured hit/miss
+    counters come back on ``SweepResult.dedup_stats``.
 
     ``async_commit`` moves shard/checkpoint commits onto a bounded
     single-worker background thread (``core.commit.ChunkCommitter``,
@@ -162,7 +142,7 @@ class SweepConfig:
     error surfacing are all preserved (worker exceptions re-raise at the
     next submit/drain; the queue drains on every exit, ``KeyboardInterrupt``
     included), and the committed BYTES are identical to the synchronous
-    path's — execution-only like ``layout``/``dedup``, never fingerprinted.
+    path's — execution-only like ``dedup``, never fingerprinted.
     ``commit_depth`` bounds how many chunk commits may be pending before
     the sweep loop blocks (host-memory backpressure).
 
@@ -177,10 +157,9 @@ class SweepConfig:
     with ``n_pods``/``chunk_size``/``MIGRATE_TOP_K``, since the epoch
     structure depends on the plan), so ``migrate_every=0`` fingerprints and
     shards stay byte-identical to the migration-less engine.  Requires
-    ``results_dir`` (the migrant files ride the shared directory) and
-    refuses ``model_axis`` (the fold runs between host-driven jit
-    segments).  Importing waits up to ``migrate_timeout`` seconds for a
-    lagging pod's migrant file before raising.
+    ``results_dir`` (the migrant files ride the shared directory).
+    Importing waits up to ``migrate_timeout`` seconds for a lagging pod's
+    migrant file before raising.
     """
     chunk_size: int = 32          # runs per jit'd batch (device-memory bound)
     checkpoint_dir: str | None = None
@@ -190,8 +169,6 @@ class SweepConfig:
     max_chunks: int | None = None  # stop after N chunks (tests/ops drains)
     n_pods: int = 1               # pod-shard the chunk plan (DESIGN.md §6)
     pod_index: int | None = None  # this process's pod (None: resolve via ctx)
-    model_axis: str | None = None  # mesh axis to shard the input cube over
-    layout: str | None = None     # Pallas grid-layout override (DESIGN.md §7)
     dedup: bool | None = None     # phenotype-dedup cache override (§8)
     dedup_cache_size: int = 1 << 16  # cross-generation LRU entry bound
     async_commit: bool = False    # background shard/checkpoint commits (§11)
@@ -209,21 +186,10 @@ class SweepConfig:
         if self.migrate_every < 0:
             raise ValueError(
                 f"migrate_every must be >= 0, got {self.migrate_every}")
-        if self.migrate_every > 0:
-            if self.results_dir is None:
-                raise ValueError(
-                    "migrate_every needs a results_dir: migrant files ride "
-                    "the shared results directory (DESIGN.md §11)")
-            if self.model_axis is not None:
-                raise ValueError(
-                    "migrate_every is incompatible with model_axis: the "
-                    "migrant fold runs between host-driven jit segments, a "
-                    "cube-sharded dispatch is one fused program "
-                    "(DESIGN.md §11)")
-        if self.layout not in (None, "auto", "genome_major", "cube_major"):
+        if self.migrate_every > 0 and self.results_dir is None:
             raise ValueError(
-                f"layout must be None, 'auto', 'genome_major' or "
-                f"'cube_major', got {self.layout!r}")
+                "migrate_every needs a results_dir: migrant files ride "
+                "the shared results directory (DESIGN.md §11)")
         if self.chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1, got {self.chunk_size}")
         if self.checkpoint_every < 1:
@@ -318,11 +284,11 @@ class SweepResult:
 # Batched core (one chunk = one XLA program)
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("spec", "cfg", "axis_name"))
+@functools.partial(jax.jit, static_argnames=("spec", "cfg"))
 def evolve_chunk(spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
                  thr_mat: jax.Array, in_planes: jax.Array,
                  golden_vals: jax.Array, golden_power: jax.Array,
-                 keys: jax.Array, axis_name: str | None = None):
+                 keys: jax.Array):
     """Evolve ``thr_mat.shape[0]`` runs in one program.
 
     The serial ``evolve`` semantics are preserved per run (same per-run PRNG
@@ -331,17 +297,10 @@ def evolve_chunk(spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
     the whole (chunk × λ) offspring population in one shot per generation —
     for ``backend="pallas"`` that is a single fused kernel dispatch with the
     genome axis on the Pallas grid.  Histories are returned run-major.
-
-    ``axis_name`` input-space-shards that dispatch (DESIGN.md §6): call
-    under ``shard_map`` with ``in_planes``/``golden_vals`` split on their
-    word/value axis and everything else replicated — evaluation partials
-    combine across the axis, so every shard holds the replicated global
-    result (``_sharded_chunk_fn`` builds exactly that wrapper).
     """
-    batched_step = make_batched_generation_step(spec, cfg,
-                                                axis_name=axis_name)
+    batched_step = make_batched_generation_step(spec, cfg)
     state0 = init_state_batched(spec, cfg, golden, thr_mat, in_planes,
-                                golden_vals, keys, axis_name=axis_name)
+                                golden_vals, keys)
     state, (hp, hm, hf) = scan_generations(batched_step, state0, thr_mat,
                                            in_planes, golden_vals,
                                            golden_power, cfg.generations)
@@ -349,7 +308,7 @@ def evolve_chunk(spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
 
 
 _init_state_batched_jit = jax.jit(
-    init_state_batched, static_argnames=("spec", "cfg", "axis_name"))
+    init_state_batched, static_argnames=("spec", "cfg"))
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "cfg"))
@@ -454,36 +413,6 @@ def _evolve_chunk_dedup(spec: CGPSpec, cfg: EvolveConfig, golden: Genome,
             np.stack(hm, axis=gens_axis), np.stack(hf, axis=gens_axis))
 
 
-@functools.lru_cache(maxsize=None)
-def _sharded_chunk_fn(mesh, model_axis: str, spec: CGPSpec,
-                      cfg: EvolveConfig):
-    """jit(shard_map(evolve_chunk)) with the input cube sharded over
-    ``model_axis`` — the pods × chunk × λ × cube-shards fusion of DESIGN.md
-    §6.  Cached per (mesh, axis, problem): the returned callable reuses one
-    trace per σ group exactly like the unsharded ``evolve_chunk``.
-
-    Per-run state/thresholds/keys are replicated (mutation and selection are
-    identical on every shard because the combined evaluation partials are);
-    outputs are therefore replicated too, which is what ``out_specs=P()``
-    with ``check_vma=False`` asserts (psum through the Pallas wrapper is
-    opaque to shard_map's replication checker).
-    """
-    from jax.sharding import PartitionSpec as P
-
-    def call(gold_nodes, gold_outs, thr_mat, in_planes, golden_vals,
-             golden_power, keys):
-        return evolve_chunk(spec, cfg, Genome(gold_nodes, gold_outs),
-                            thr_mat, in_planes, golden_vals, golden_power,
-                            keys, axis_name=model_axis)
-
-    rep = P()
-    fn = jax.shard_map(call, mesh=mesh,
-                       in_specs=(rep, rep, rep, P(None, model_axis),
-                                 P(model_axis), rep, rep),
-                       out_specs=rep, check_vma=False)
-    return jax.jit(fn)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("spec", "gauss_sigma", "sampled"))
 def characterize_chunk(spec: CGPSpec, gauss_sigma: float, nodes: jax.Array,
@@ -518,22 +447,6 @@ def characterize_chunk(spec: CGPSpec, gauss_sigma: float, nodes: jax.Array,
         genomes, probs)
     return (met, sterr, cost.power / golden_power,
             jax.vmap(feasible)(met, thr_mat), partials.count, moments)
-
-
-@functools.lru_cache(maxsize=None)
-def _replicated_characterize_fn(mesh, spec: CGPSpec, gauss_sigma: float,
-                                sampled: bool):
-    """``characterize_chunk`` on every chip of ``mesh``, each over the whole
-    cube (gathered where a cube-sharded sweep holds it sharded): the
-    records of a cube-sharded sweep are the unsharded sweep's, bit for
-    bit."""
-    from jax.sharding import PartitionSpec as P
-
-    def call(*args):
-        return characterize_chunk(spec, gauss_sigma, *args, sampled=sampled)
-
-    return jax.jit(jax.shard_map(call, mesh=mesh, in_specs=P(),
-                                 out_specs=P(), check_vma=False))
 
 
 def finish_moments(count, moments) -> tuple[np.ndarray, np.ndarray]:
@@ -597,7 +510,7 @@ def grid_fingerprint(cfg, grid, keep_history: str | bool,
             np.stack([con.thresholds() for con, _ in grid]).tobytes()
         ).hexdigest(),
     }
-    # eval_mode is RESULT-changing (unlike layout/dedup): sampled grids key
+    # eval_mode is RESULT-changing (unlike dedup): sampled grids key
     # on the full sample-stream identity so a checkpoint/shard set can never
     # resume under different evaluation inputs.  Exhaustive grids omit the
     # keys entirely — their fingerprints (and hence pre-§9 checkpoints)
@@ -739,23 +652,7 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
     n_escalated = 0
 
     dedup = sweep.dedup if sweep.dedup is not None else cfg.evolve.dedup
-    if dedup and sweep.model_axis is not None:
-        # diagnosed before the mesh check: the incompatibility holds
-        # whether or not a mesh is active
-        raise ValueError(
-            "dedup is incompatible with model_axis: the dedup generation "
-            "loop is host-driven, a cube-sharded dispatch is one fused "
-            "program (DESIGN.md §8)")
     cache = PhenotypeLRU(sweep.dedup_cache_size) if dedup else None
-
-    if sweep.model_axis is not None:
-        from repro.parallel import ctx
-        mesh = ctx.get_mesh()
-        if mesh is None or sweep.model_axis not in mesh.axis_names:
-            raise ValueError(
-                f"model_axis {sweep.model_axis!r} needs an active "
-                f"parallel.ctx mesh carrying that axis (have: "
-                f"{None if mesh is None else mesh.axis_names})")
 
     bufs = _alloc_buffers(spec, n_runs, gens, mode)
     migrating = sweep.migrate_every > 0
@@ -867,8 +764,6 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
             orig = sel[:n]  # grid-order rows this chunk fills
             sigma = float(sigmas[orig[0]])
             ecfg = dataclasses.replace(cfg.evolve, gauss_sigma=sigma, seed=0)
-            if sweep.layout is not None:
-                ecfg = dataclasses.replace(ecfg, layout=sweep.layout)
 
             state0 = None
             if migrator is not None:
@@ -889,13 +784,7 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
                         migrator.stats["adopted"] += int(n_adopt)
 
             with jax.profiler.TraceAnnotation("sweep.dispatch"):
-                if sweep.model_axis is not None:
-                    evolve_call = _sharded_chunk_fn(
-                        ctx.get_mesh(), sweep.model_axis, spec, ecfg)
-                    state, hp, hm, hf = evolve_call(
-                        gold.nodes, gold.outs, jnp.asarray(thr[sel]),
-                        in_planes, gvals, gpower, jnp.asarray(keys[sel]))
-                elif dedup:
+                if dedup:
                     state, hp, hm, hf = _evolve_chunk_dedup(
                         spec, ecfg, gold, jnp.asarray(thr[sel]), in_planes,
                         gvals, gpower, jnp.asarray(keys[sel]), cache,
@@ -908,16 +797,10 @@ def run_sweep_batched(cfg, constraints: Sequence[ConstraintSpec],
                     state, hp, hm, hf = evolve_chunk(
                         spec, ecfg, gold, jnp.asarray(thr[sel]), in_planes,
                         gvals, gpower, jnp.asarray(keys[sel]))
-                final = (state.parent.nodes, state.parent.outs,
-                         jnp.asarray(thr[sel]), in_planes, gvals, gpower)
-                if sweep.model_axis is not None:
-                    met, sterr, prel, feas, count, moments = \
-                        _replicated_characterize_fn(
-                            ctx.get_mesh(), spec, sigma, sampled)(*final)
-                else:
-                    met, sterr, prel, feas, count, moments = \
-                        characterize_chunk(spec, sigma, *final,
-                                           sampled=sampled)
+                met, sterr, prel, feas, count, moments = characterize_chunk(
+                    spec, sigma, state.parent.nodes, state.parent.outs,
+                    jnp.asarray(thr[sel]), in_planes, gvals, gpower,
+                    sampled=sampled)
 
             # histories stay in RAM ("full") or spill per chunk to a shard
             keep_hist = mode == "full" or (writer is not None

@@ -8,36 +8,22 @@ same pass unpacks integer outputs and accumulates every error-metric partial
 so a candidate costs exactly one HBM read of its input-plane block and one
 accumulator row of write-back.
 
-Two evaluation-grid LAYOUTS share one kernel body (DESIGN.md §7; the Pallas
-grid runs sequentially with the LAST dimension innermost):
+One dispatch evaluates R stacked genomes over the whole input cube on one
+chip, on the grid ``(Rp, W // bw)`` (DESIGN.md §7; the Pallas grid runs
+sequentially with the LAST dimension innermost): the GENOME axis is grid
+dimension 0 (one sweep chunk of ``runs × λ`` candidates, which
+``core.sweep``/``core.evolve`` flatten into it, padded to ``Rp``) and the
+cube-block axis is dimension 1, so each genome streams the input cube from
+HBM once — the paper's per-candidate traffic; what the fused grid removes is
+the per-genome dispatch and trace overhead.
 
-* ``layout="genome_major"`` — grid ``(R, W // bw)``: the GENOME axis is grid
-  dimension 0 (one sweep-chunk of ``runs × λ`` candidates per dispatch,
-  ``core.sweep``/``core.evolve`` flatten the population into it) and the
-  input-cube block axis is dimension 1, so each genome streams the input
-  cube from HBM once — the paper's per-candidate traffic; what the fused
-  grid removes is the per-genome dispatch/trace overhead.
-* ``layout="cube_major"`` — the transposed grid ``(W // bw, R)``: the cube
-  block axis is OUTER and the genome axis inner, so one cube block is loaded
-  once and reused across the whole (chunk × λ) population before the next
-  block streams in — per-dispatch HBM cube traffic drops from R reads of the
-  cube to ONE (the input-plane/golden index maps ignore the genome index, so
-  the pipeliner skips the re-fetch between consecutive steps).
+The ``(Rp, cols)`` float32 accumulator stays whole in VMEM for the entire
+grid (its block index never changes, so it is written back to HBM once,
+after the last step).  Grid step (genome r, block w) sets row r at w == 0
+and combines into it afterwards (then carries the digits of the exact sums,
+below), so every genome's cube blocks accumulate in ascending order.
 
-Both layouts write ONE ``(R, cols)`` float32 accumulator that stays whole in
-VMEM for the entire grid (its block index never changes, so it is written
-back to HBM once, after the last step).  Grid step (genome r, block w) sets
-row r at w == 0 and combines into it afterwards (then carries the digits of
-the exact sums, below), so every genome's cube
-blocks accumulate in the same ascending order in either layout and the
-outputs are bit-identical (including the float32 ``rel_sum`` column) —
-layout is a pure execution knob, picked per (width, R, backend) by
-``kernels.tune`` when callers pass ``layout="auto"``.  Input-space sharding
-composes with the fused grid in either layout through
-``cgp_sim_metrics_batched_sharded`` (per-genome accumulators psum/pmax
-across the mesh axis — DESIGN.md §6).
-
-Mosaic (the TPU compiler) constraints the layout follows — interpret mode
+Mosaic (the TPU compiler) constraints the kernel follows — interpret mode
 checks none of them, ``tests/test_tpu_compile.py`` compiles for a described
 v5e chip to keep them honest:
 
@@ -87,7 +73,7 @@ allow: 2,048 words (the whole cube, ``(16, 128)``) at width 8, 1,024
 (``(8, 128)``) where the kernel carries, 512 (``(4, 128)``) with
 ``moments``.  The
 wrapper returns every digit fully carried (``carry_digits``), so a total
-has one row however the cube was cut into blocks or shards.  With
+has one row however the cube was cut into blocks.  With
 ``moments`` (the sweep's final measurement, ``core.sweep.
 characterize_chunk``) the ``SQ_DIGITS`` digits of Σd² (below 2^72) follow,
 built the same way from the bytes of the products of the bytes of |d|
@@ -381,7 +367,7 @@ def _digit_groups(moments: bool) -> tuple[tuple[int, int], ...]:
 
 def _canonical(sums: jax.Array) -> jax.Array:
     """``sums`` with each exact sum's digits fully carried: one value has
-    one row, however the cube was cut into blocks or shards."""
+    one row, however the cube was cut into blocks."""
     groups = _digit_groups(sums.shape[-1] == N_SUMS_MOMENTS)
     return jnp.concatenate([sums[..., :ABS_DIGITS]] + [
         carry_digits(sums[..., at:at + n].astype(jnp.int32)).astype(
@@ -405,14 +391,13 @@ def _carry(row: jax.Array, s: int, moments: bool) -> jax.Array:
 
 
 def cgp_sim_kernel(genome_ref, planes_ref, golden_ref, acc_ref, wires, *,
-                   genome_axis: int, n_i: int, n_n: int, n_o: int,
-                   edges: tuple[float, ...], moments: bool, carry: bool):
-    """Grid step (genome r, cube block w) in either layout: row r of the
-    VMEM-resident accumulator is set at w == 0 and combined into after:
-    the WCE column by max, the others by addition, then (``carry``) the
-    digits carry."""
-    r = pl.program_id(genome_axis)
-    blk = pl.program_id(1 - genome_axis)
+                   n_i: int, n_n: int, n_o: int, edges: tuple[float, ...],
+                   moments: bool, carry: bool):
+    """Grid step (genome r, cube block w): row r of the VMEM-resident
+    accumulator is set at w == 0 and combined into after: the WCE column by
+    max, the others by addition, then (``carry``) the digits carry."""
+    r = pl.program_id(0)
+    blk = pl.program_id(1)
     row = _block_row(genome_ref, planes_ref, golden_ref, wires, n_i=n_i,
                      n_n=n_n, n_o=n_o, edges=edges, moments=moments)
     acc = acc_ref.at[pl.ds(r, 1), :]
@@ -446,14 +431,12 @@ def _resolve_interpret(interpret: bool | None) -> bool:
 @functools.partial(
     jax.jit,
     static_argnames=("n_i", "n_n", "n_o", "gauss_sigma", "n_gauss_side",
-                     "block_words", "r_tile", "layout", "moments",
-                     "interpret"))
+                     "block_words", "r_tile", "moments", "interpret"))
 def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
                             in_planes: jax.Array, golden_vals: jax.Array,
                             *, n_i: int, n_n: int, n_o: int,
                             gauss_sigma: float = 256.0, n_gauss_side: int = 4,
                             block_words: int | None = None, r_tile: int = 8,
-                            layout: str = "genome_major",
                             moments: bool = False,
                             interpret: bool | None = None):
     """Fused (runs × λ) pallas_call: ONE dispatch for R stacked genomes.
@@ -467,13 +450,6 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
       r_tile: pad of the genome axis; R is padded up to a multiple with
         copies of the last genome, sliced off on return, so ragged R (e.g. a
         non-multiple sweep-chunk tail) is transparent.
-      layout: evaluation-grid order (DESIGN.md §7).  ``"genome_major"`` puts
-        the genome axis on grid dim 0 (cube innermost); ``"cube_major"``
-        transposes the grid (cube outer, genomes inner) so one cube block is
-        reused across the whole population.  Outputs are bit-identical
-        across layouts; resolve ``"auto"`` upstream (``kernels.tune`` /
-        ``ops.cgp_eval_batched``) — this function only accepts the two
-        concrete spellings.
       moments: add the digits of Σd² to the sums.
       interpret: Pallas interpret mode; ``None`` takes
         ``ops.default_interpret()`` (on only off-TPU).
@@ -485,10 +461,6 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
     range: more than ``MAX_ROWS`` rows, more than ``MAX_N_O`` outputs, or
     a block whose digit sums could pass 2^24.
     """
-    if layout not in ("genome_major", "cube_major"):
-        raise ValueError(
-            f"layout must be 'genome_major' or 'cube_major', got {layout!r} "
-            "(resolve 'auto' via kernels.tune before the kernel call)")
     interpret = _resolve_interpret(interpret)
     R = nodes.shape[0]
     genomes = jnp.concatenate([nodes.reshape(R, 3 * n_n), outs], axis=1)
@@ -517,29 +489,25 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
     n_s = n_sums(moments)
     cols = -(-(n_wires + n_s + 1 + n_bins) // 8) * 8
 
-    genome_axis = 0 if layout == "genome_major" else 1
-    grid = (Rp, W // bw) if genome_axis == 0 else (W // bw, Rp)
-    genome_of = lambda *ij: ij[genome_axis]
-    block_of = lambda *ij: (0, ij[1 - genome_axis], 0, 0)
+    block_of = lambda r, w: (0, w, 0, 0)
     # (rows, W) -> (rows, blocks, S, L): a block's words sublane-dense; S
     # is a whole dimension, so any S is a legal block (squeezed at S = 1)
     dense = lambda x: x.reshape(x.shape[0], W // bw, S, L)
     words = (S, L) if S > 1 else (None, L)
-    kernel = functools.partial(cgp_sim_kernel, genome_axis=genome_axis,
-                               n_i=n_i, n_n=n_n, n_o=n_o, edges=edges,
-                               moments=moments,
+    kernel = functools.partial(cgp_sim_kernel, n_i=n_i, n_n=n_n, n_o=n_o,
+                               edges=edges, moments=moments,
                                carry=_carry_slack(W, n_o, moments) > 0)
     acc = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(Rp, W // bw),
         in_specs=[
             pl.BlockSpec((None, 1, genomes.shape[1]),
-                         lambda *ij: (genome_of(*ij), 0, 0),
+                         lambda r, w: (r, 0, 0),
                          memory_space=pltpu.SMEM),           # one genome
             pl.BlockSpec((n_i, None) + words, block_of),
             pl.BlockSpec((32, None) + words, block_of),
         ],
-        out_specs=pl.BlockSpec((Rp, cols), lambda *ij: (0, 0)),
+        out_specs=pl.BlockSpec((Rp, cols), lambda r, w: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, cols), jnp.float32),
         scratch_shapes=[pltpu.VMEM((cols * S, L), jnp.int32)],  # wires
         interpret=interpret,
@@ -550,53 +518,6 @@ def cgp_sim_metrics_batched(nodes: jax.Array, outs: jax.Array,
             acc[:, s + n_s:s + n_s + 1].astype(jnp.int32),
             acc[:, s + n_s + 1:s + n_s + 1 + n_bins],
             acc[:, n_i:n_wires])
-
-
-def cgp_sim_metrics_batched_sharded(nodes: jax.Array, outs: jax.Array,
-                                    in_planes: jax.Array,
-                                    golden_vals: jax.Array, *,
-                                    axis_name: str, n_i: int, n_n: int,
-                                    n_o: int, gauss_sigma: float = 256.0,
-                                    n_gauss_side: int = 4,
-                                    block_words: int | None = None,
-                                    r_tile: int = 8,
-                                    layout: str = "genome_major",
-                                    interpret: bool | None = None):
-    """Cube-shard variant of the fused batched kernel (DESIGN.md §6).
-
-    Runs under input-space sharding (``shard_map`` with the cube's word axis
-    split over ``axis_name``, conventionally the ``model`` mesh axis): every
-    shard dispatches the SAME (runs × λ) Pallas grid on its local
-    ``in_planes``/``golden_vals`` slice, then the per-genome accumulators
-    combine across the axis — sums/histogram/popcounts (and the count row
-    inside ``sums``) psum, the worst-case-error row pmax.  The psum contract
-    stays exact for the integer-valued accumulators: every per-shard count
-    is at most the 2^24 rows of the whole cube (``MAX_ROWS`` checked on the
-    whole), and every per-shard digit comes carried, below 2^8, so float32
-    psum is associative on them; carried again after the psum, the digits
-    are the unsharded kernel's, bit for bit (``rel_sum`` alone is
-    genuinely floating-point and only reassociation-close).
-
-    This is what lets a pod's whole (chunk × λ) population fuse into one
-    dispatch per generation even when the cube is sharded —
-    ``evolve._eval_pop_pallas`` previously had to fall back to a vmap of
-    per-genome kernels whenever ``axis_name`` was set.
-
-    Same signature/returns as ``cgp_sim_metrics_batched`` plus ``axis_name``;
-    ``in_planes`` is ``(n_i, W_local)`` and ``golden_vals`` ``(W_local*32,)``
-    — this shard's word slice.  Must be called inside a context where
-    ``axis_name`` is bound (it is not independently jit-able).
-    """
-    if 32 * in_planes.shape[1] * jax.lax.axis_size(axis_name) > MAX_ROWS:
-        raise ValueError(f"the sharded cube passes {MAX_ROWS} rows")
-    sums, wce, hist, pops = cgp_sim_metrics_batched(
-        nodes, outs, in_planes, golden_vals, n_i=n_i, n_n=n_n, n_o=n_o,
-        gauss_sigma=gauss_sigma, n_gauss_side=n_gauss_side,
-        block_words=block_words, r_tile=r_tile, layout=layout,
-        interpret=interpret)
-    return (_canonical(jax.lax.psum(sums, axis_name)),
-            jax.lax.pmax(wce, axis_name),
-            jax.lax.psum(hist, axis_name), jax.lax.psum(pops, axis_name))
 
 
 @functools.partial(

@@ -17,7 +17,6 @@ from repro.core.genome import CGPSpec, Genome
 from repro.kernels import cgp_sim as _cgp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import lut_matmul as _lut
-from repro.kernels import tune as _tune
 
 
 def _on_tpu() -> bool:
@@ -122,80 +121,35 @@ def cgp_eval_batched(genomes: Genome, spec: CGPSpec, in_planes: jax.Array,
                      golden_vals: jax.Array, gauss_sigma: float = 256.0,
                      block_words: int | None = None,
                      interpret: bool | None = None,
-                     r_tile: int | None = None, axis_name: str | None = None,
-                     layout: str = "auto", moments: bool = False):
+                     r_tile: int | None = None, moments: bool = False):
     """Fused (runs × λ) population evaluation in ONE kernel dispatch.
 
     ``genomes`` carries a leading stacked axis R: nodes (R, n_n, 3), outs
-    (R, n_o).  The genome axis becomes Pallas grid dimension 0 — this
+    (R, n_o).  The genome axis becomes Pallas grid dimension 0 and the
+    whole input cube is evaluated on this chip (DESIGN.md §7) — this
     replaces ``jax.vmap(cgp_eval)`` over a population, which dispatched one
     kernel per genome (or one vmap-batched program) and left the run axis
     off the grid.  Returns (MetricPartials with leading R, pops (R, n_n));
-    with ``moments`` (unsharded only) the partials' ``moments`` hold the
-    digits of the exact Σ|d|, Σd and Σd² (``exact_sums``).
+    with ``moments`` the partials' ``moments`` hold the digits of the exact
+    Σ|d|, Σd and Σd² (``exact_sums``).
 
-    With ``axis_name`` the input cube is sharded over that mesh axis:
-    ``in_planes``/``golden_vals`` are this shard's word slice, the local
-    dispatch is unchanged, and the per-genome accumulators are combined
-    across the axis before decoding (``cgp_sim_metrics_batched_sharded`` —
-    psum for the sums/histogram/popcount rows, pmax for WCE), so the
-    returned partials and popcounts are already cube-global.  Only callable
-    where the axis is bound (e.g. under ``shard_map``).
-
-    ``block_words=None`` / ``r_tile=None`` pick the kernel execution point
-    automatically: under ``layout="auto"`` the whole MEASURED winner variant
-    is adopted — layout AND block size AND genome-axis pad together, since
-    the tuning pass times them jointly (``kernels.tune.resolve_variant``,
-    keyed by (width, R, backend); a half-adopted variant could be slower
-    than either the winner or the default).  With an explicit layout, or no
-    table entry, the defaults are the largest block whose digit sums stay
-    exact (``cgp_sim.default_block_words``: 2,048 words at width 8, 1,024
-    at width 12, 512 with ``moments``) and the interpret-aware pad (8 when
-    compiled, 1 interpreted: interpret mode pays every pad row as a full
-    recomputed evaluation).  Passing either knob explicitly overrides the
-    tuned value for that knob only.
-
-    ``layout`` picks the evaluation-grid order (DESIGN.md §7):
-    ``"genome_major"`` (cube innermost per genome) or ``"cube_major"``
-    (transposed grid — one cube block reused across the whole population,
-    per-genome accumulators in flushed VMEM scratch).  Results are
-    bit-identical either way.  The default ``"auto"`` resolves through the
-    measured tuning table; with no table entry it falls back to
-    genome-major.  Resolution happens at trace time (R and the backend are
-    static), so it costs nothing per step.
+    ``block_words=None`` takes the kernel's shape rule, the largest block
+    whose digit sums stay exact (``cgp_sim.default_block_words``: 2,048
+    words at width 8, 1,024 at width 12, 512 with ``moments``), and
+    ``r_tile=None`` pads the genome axis to a multiple of 8 when compiled
+    and 1 interpreted (interpret mode pays every pad row as a full
+    evaluation).  Either may be passed explicitly: the integer outputs do
+    not depend on them, the float32 sums only through their order.
     """
     if interpret is None:
         interpret = default_interpret()
-    variant = None
-    if layout == "auto":
-        # on a full table miss, fall back to the same execution point an
-        # explicit layout would get (incl. the interpret-aware pad)
-        variant = _tune.resolve_variant(
-            spec.n_i // 2, genomes.nodes.shape[0],
-            _tune.backend_key(interpret),
-            default=_tune.KernelVariant(r_tile=1 if interpret else 8))
-        layout = variant.layout
-    if block_words is None and variant is not None:
-        # tuned blocks are measured per width, and every candidate is a
-        # power of two, so they divide any (power-of-two) cube shard too;
-        # None (no table entry) leaves the block to the kernel's shape rule
-        block_words = variant.block_words
     if r_tile is None:
-        r_tile = variant.r_tile if variant is not None \
-            else (1 if interpret else 8)
-    kw = dict(n_i=spec.n_i, n_n=spec.n_n, n_o=spec.n_o,
-              gauss_sigma=gauss_sigma, block_words=block_words,
-              r_tile=r_tile, layout=layout, interpret=interpret)
-    if axis_name is None:
-        sums, wce, hist, pops = _cgp.cgp_sim_metrics_batched(
-            genomes.nodes, genomes.outs, in_planes, golden_vals,
-            moments=moments, **kw)
-    elif moments:
-        raise ValueError("the moments are measured on the whole cube")
-    else:
-        sums, wce, hist, pops = _cgp.cgp_sim_metrics_batched_sharded(
-            genomes.nodes, genomes.outs, in_planes, golden_vals,
-            axis_name=axis_name, **kw)
+        r_tile = 1 if interpret else 8
+    sums, wce, hist, pops = _cgp.cgp_sim_metrics_batched(
+        genomes.nodes, genomes.outs, in_planes, golden_vals,
+        n_i=spec.n_i, n_n=spec.n_n, n_o=spec.n_o, gauss_sigma=gauss_sigma,
+        block_words=block_words, r_tile=r_tile, moments=moments,
+        interpret=interpret)
     return _partials_from_sums(sums, wce, hist), pops
 
 

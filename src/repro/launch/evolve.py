@@ -61,15 +61,6 @@ def main(argv=None):
                     help="candidate evaluation: pure-jnp or the fused "
                          "(runs x lambda) Pallas kernel (one dispatch per "
                          "generation in the batched engine; interpret on CPU)")
-    ap.add_argument("--layout", default="auto",
-                    choices=["auto", "genome_major", "cube_major"],
-                    help="Pallas evaluation-grid order (backend=pallas, "
-                         "DESIGN.md section 7): genome_major streams the "
-                         "input cube per genome, cube_major reuses each "
-                         "cube block across the whole population (VMEM "
-                         "scratch accumulators); auto resolves the measured "
-                         "tuning table.  Results are bit-identical either "
-                         "way")
     ap.add_argument("--dedup", action="store_true",
                     help="phenotype-dedup evaluation cache (DESIGN.md "
                          "section 8): evaluate each unique active subgraph "
@@ -215,7 +206,7 @@ def main(argv=None):
     cfg = SearchConfig(
         width=args.width, kind=args.kind, n_n=args.nodes,
         evolve=EvolveConfig(generations=args.generations, lam=args.lam,
-                            backend=args.backend, layout=args.layout,
+                            backend=args.backend,
                             eval_mode=args.eval_mode,
                             sample_size=args.sample_size,
                             input_dist=args.input_dist,
@@ -240,7 +231,7 @@ def main(argv=None):
         sweep = SweepConfig(chunk_size=args.chunk_size,
                             checkpoint_dir=args.checkpoint_dir,
                             results_dir=args.results_dir,
-                            keep_history=mode, layout=args.layout,
+                            keep_history=mode,
                             n_pods=args.pods, pod_index=pod,
                             dedup=args.dedup or None,
                             dedup_cache_size=args.dedup_cache_size,

@@ -7,8 +7,8 @@ module never touches jax device state.  Axis semantics (DESIGN.md §2.2/§5):
     partition of the pod-sharded sweep for CGP cells (DESIGN.md §6 — pods
     run disjoint chunk slices, no cross-pod collectives).
   * ``data``  — batch parallelism (LM) / evolution islands (CGP).
-  * ``model`` — tensor parallelism (LM) / input-cube sharding (CGP: metric
-    partials psum across it).
+  * ``model`` — tensor parallelism (LM) / input-cube sharding of the jnp
+    island path (CGP: metric partials psum across it).
 
 The logical names model code uses resolve against these physical axes in
 ``parallel.ctx.LOGICAL``.
@@ -55,8 +55,7 @@ def make_host_mesh():
 def make_sweep_mesh(pods: int = 1):
     """All local devices as a (pod, data, model) mesh for the pod-sharded
     sweep (DESIGN.md §6): ``pods`` slices of the constraint grid, the rest
-    of the devices on the ``model`` axis for input-cube sharding
-    (``SweepConfig.model_axis="model"``), a singleton ``data`` axis.
+    of the devices on the ``model`` axis, a singleton ``data`` axis.
 
     Host-local stand-in for the multi-pod production mesh: with a forced
     device count this is what the multi-pod parity tests drive

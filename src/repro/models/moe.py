@@ -112,7 +112,7 @@ def moe_ffn(params: Tree, x: jax.Array, cfg: ModelConfig,
 
 
 def moe_ffn_ep(params: Tree, x: jax.Array, cfg: ModelConfig,
-               mesh, model_axis: str = "model",
+               mesh, expert_axis: str = "model",
                full_capacity: bool = False):
     """Explicit expert-parallel path (shard_map over the model axis).
 
@@ -124,7 +124,7 @@ def moe_ffn_ep(params: Tree, x: jax.Array, cfg: ModelConfig,
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
-    n_shards = mesh.shape[model_axis]
+    n_shards = mesh.shape[expert_axis]
     assert m.n_experts % n_shards == 0
     e_per = m.n_experts // n_shards
 
@@ -132,14 +132,14 @@ def moe_ffn_ep(params: Tree, x: jax.Array, cfg: ModelConfig,
         B, S, d = x_l.shape
         p_l = {"norm": norm, "router": router, "w_gate": w_gate,
                "w_up": w_up, "w_down": w_down}
-        shard = jax.lax.axis_index(model_axis)
+        shard = jax.lax.axis_index(expert_axis)
         h = rms_norm(x_l, norm, cfg.norm_eps)
         e_lo = shard * e_per
         out, aux = _moe_math_dyn(h.reshape(B * S, d), p_l, cfg, e_lo, e_per,
                                  capacity=_capacity(B * S, cfg,
                                                     full_capacity))
-        out = jax.lax.psum(out, model_axis)
-        aux = jax.lax.pmean(aux, model_axis)
+        out = jax.lax.psum(out, expert_axis)
+        aux = jax.lax.pmean(aux, expert_axis)
         return x_l + out.reshape(B, S, d), aux
 
     # shard the token batch over data only when divisible (decode at tiny
@@ -155,8 +155,8 @@ def moe_ffn_ep(params: Tree, x: jax.Array, cfg: ModelConfig,
     fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(data_spec, P(None), P(None, None),
-                  P(model_axis, None, None), P(model_axis, None, None),
-                  P(model_axis, None, None)),
+                  P(expert_axis, None, None), P(expert_axis, None, None),
+                  P(expert_axis, None, None)),
         out_specs=(data_spec, P()),
         check_vma=False)
     return fn(x, params["norm"], params["router"], params["w_gate"],

@@ -136,12 +136,9 @@ def test_migration_off_fingerprint_and_bytes_unchanged(tmp_path):
         assert json.load(f)["grid_fingerprint"] == base
 
 
-def test_migration_config_validated(tmp_path):
+def test_migration_config_validated():
     with pytest.raises(ValueError, match="results_dir"):
         SweepConfig(migrate_every=1)
-    with pytest.raises(ValueError, match="model_axis"):
-        SweepConfig(migrate_every=1, results_dir=str(tmp_path),
-                    model_axis="model")
     with pytest.raises(ValueError, match="migrate_every"):
         SweepConfig(migrate_every=-1)
 

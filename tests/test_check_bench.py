@@ -12,7 +12,7 @@ CHECK = os.path.join(REPO, "tools", "check_bench.py")
 BASELINE = {
     "sweep": {"n_runs": 6, "serial_runs_per_s": 2.0,
               "batched_jnp_runs_per_s": 20.0,
-              "batched_pallas_cube_major_runs_per_s": 10.0},
+              "batched_pallas_runs_per_s": 10.0},
     "gen": {"generations_per_s": 100.0},
     "results": {"spill_rows_per_s": 1e4, "row_kb": 7.0},
     "eval": {"fused_us_per_eval": 50.0},

@@ -222,20 +222,6 @@ def test_dedup_knob_inherits_evolve_config():
     res = run_sweep_batched(cfg, CONSTRAINTS[:1], (0,),
                             SweepConfig(chunk_size=1))
     assert res.dedup_stats is not None  # SweepConfig.dedup=None defers
-    # explicit False overrides the EvolveConfig default: the dedup/model_axis
-    # incompatibility (diagnosed before the mesh check) is NOT tripped, so
-    # the error is the mesh one
-    with pytest.raises(ValueError, match="mesh"):
-        run_sweep_batched(cfg, CONSTRAINTS[:1], (0,),
-                          SweepConfig(chunk_size=1, dedup=False,
-                                      model_axis="model"))
-
-
-def test_dedup_refuses_model_axis():
-    with pytest.raises(ValueError, match="dedup"):
-        run_sweep_batched(CFG, CONSTRAINTS[:1], (0,),
-                          SweepConfig(chunk_size=1, dedup=True,
-                                      model_axis="model"))
 
 
 def test_dedup_cache_size_validated():

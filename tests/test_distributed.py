@@ -184,43 +184,6 @@ print('MIGRATE-MESH-OK', len(files))
     assert "MIGRATE-MESH-OK" in out
 
 
-@pytest.mark.slow
-def test_model_sharded_sweep_dispatch_matches_unsharded():
-    """SweepConfig.model_axis: the (chunk × λ) dispatch with the input cube
-    shard_map'd over the model axis (evaluation partials psum through the
-    cube-shard kernel variant) is bit-identical to the unsharded dispatch —
-    for BOTH backends; the pallas leg exercises the fused batched kernel
-    under sharding, which used to fall back to a per-genome vmap."""
-    out = run_subprocess("""
-import sys, dataclasses; sys.path.insert(0, 'src')
-import numpy as np
-from repro.core.evolve import EvolveConfig
-from repro.core.fitness import ConstraintSpec
-from repro.core.search import SearchConfig
-from repro.core.sweep import SweepConfig, run_sweep_batched
-from repro.launch.mesh import make_sweep_mesh
-from repro.parallel import ctx
-
-CFG = SearchConfig(width=3, kind='mul', n_n=60,
-                   evolve=EvolveConfig(generations=30, lam=3))
-CONS = [ConstraintSpec(mae=2.0), ConstraintSpec(er=50.0)]
-plain = run_sweep_batched(CFG, CONS, (0, 1), SweepConfig(chunk_size=3))
-mesh = make_sweep_mesh(pods=1)  # (1, 1, 2): both devices on model
-for backend in ('jnp', 'pallas'):
-    cfg = dataclasses.replace(
-        CFG, evolve=dataclasses.replace(CFG.evolve, backend=backend))
-    with ctx.use_mesh(mesh):
-        sharded = run_sweep_batched(cfg, CONS, (0, 1), SweepConfig(
-            chunk_size=3, model_axis='model'))
-    assert sharded.completed == plain.completed
-    for a, b in zip(plain.records, sharded.records):
-        assert (a.genome_nodes == b.genome_nodes).all(), backend
-        assert (a.genome_outs == b.genome_outs).all(), backend
-        np.testing.assert_array_equal(a.metrics, b.metrics)
-    np.testing.assert_array_equal(plain.hist_fit, sharded.hist_fit)
-print('MODEL-SHARD-SWEEP-OK')
-""", devices=2)
-    assert "MODEL-SHARD-SWEEP-OK" in out
 
 
 @pytest.mark.slow

@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from repro.core import metrics as M
-from repro.core.evolve import EvolveConfig, evolve
+from repro.core.evolve import (EvolveConfig, _eval_pallas,
+                               _eval_pop_pallas, evolve)
 from repro.core.fitness import ConstraintSpec, feasible, fitness
 from repro.core.search import SearchConfig, problem_arrays, run_search
 
@@ -25,6 +26,21 @@ def test_boolean_constraints_lower_bounded():
     v = jnp.zeros((M.N_METRICS,))
     assert not bool(feasible(v, thr))            # acc0 = 0 -> infeasible
     assert bool(feasible(v.at[M.ACC0].set(1.0), thr))
+
+
+def test_layout_field_accepts_only_genome_major():
+    assert EvolveConfig().layout == "genome_major"
+    for layout in ("cube_major", "auto"):
+        with pytest.raises(ValueError, match="layout"):
+            EvolveConfig(layout=layout)
+
+
+@pytest.mark.parametrize("eval_fn", [_eval_pallas, _eval_pop_pallas])
+def test_pallas_refuses_a_sharded_cube(eval_fn):
+    """The kernel evaluates the whole cube on one chip: an axis to combine
+    cube shards over is refused before anything is traced."""
+    with pytest.raises(ValueError, match="whole cube"):
+        eval_fn(None, None, None, None, 256.0, "model")
 
 
 def _run(width=3, gens=400, lam=6, con=None, seed=0, n_n=100):

@@ -5,8 +5,8 @@ At widths 9 and 10 a genome's Σ|d|, Σd and Σd² pass 2^24 (Σd² passes
 Random genomes run in interpret mode with small ``block_words`` so that
 their cube spans many blocks and every combine carries; each partial is
 held against an int64 NumPy oracle, integers exactly.  The same oracle
-holds every sublane-dense wire block, ``(1, L)`` to ``(16, 128)``, in both
-grid layouts, and their integer outputs are bit-identical.  Then the final
+holds every sublane-dense wire block, ``(1, L)`` to ``(16, 128)``, and
+their integer outputs are bit-identical.  Then the final
 measurement (``core.sweep.characterize_chunk``), now one kernel dispatch,
 against ``metrics_np`` and float64 moments, on random genomes and on
 errors that are a constant offset.
@@ -135,9 +135,9 @@ FLOAT_SUMS = [cgp_sim.REL_SUM, cgp_sim.SQ_SUM, cgp_sim.REL_SQ]
 ])
 def test_dense_wire_blocks_exact_and_bit_identical(width, n_o, blocks,
                                                    moments):
-    """Every wire block shape, in both layouts: the oracle's integers
-    exactly, integer outputs bit-identical across blocks and layouts, the
-    float32 sums equal up to their summation order."""
+    """Every wire block shape: the oracle's integers exactly, integer
+    outputs bit-identical across blocks, the float32 sums equal up to their
+    summation order."""
     spec = CGPSpec(n_i=2 * width, n_o=n_o, n_n=16)
     planes = S.input_planes(spec.n_i)
     gold = G.golden_values(width, "mul") >> (2 * width - n_o)
@@ -145,26 +145,25 @@ def test_dense_wire_blocks_exact_and_bit_identical(width, n_o, blocks,
     W = planes.shape[1]
     first = None
     for bw in blocks:
-        for layout in ("genome_major", "cube_major"):
-            call = lambda: cgp_sim.cgp_sim_metrics_batched(
-                genomes.nodes, genomes.outs, planes, jnp.asarray(gold),
-                n_i=spec.n_i, n_n=spec.n_n, n_o=spec.n_o, block_words=bw,
-                r_tile=1, layout=layout, moments=moments, interpret=True)
-            if bw > cgp_sim.default_block_words(W, n_o, moments):
-                with pytest.raises(ValueError, match="pass 2"):
-                    call()
-                continue
-            out = [np.asarray(a) for a in call()]
-            _assert_exact(out, genomes, spec, gold, moments)
-            ints = [np.delete(out[0], FLOAT_SUMS, axis=1)] + out[1:]
-            if first is None:
-                first = ints, out[0][:, FLOAT_SUMS]
-                continue
-            for got, want in zip(ints, first[0]):
-                np.testing.assert_array_equal(got, want, err_msg=(bw, layout))
-            # float32 sums of 2^16 terms, in another order
-            np.testing.assert_allclose(out[0][:, FLOAT_SUMS], first[1],
-                                       rtol=1e-4)
+        call = lambda: cgp_sim.cgp_sim_metrics_batched(
+            genomes.nodes, genomes.outs, planes, jnp.asarray(gold),
+            n_i=spec.n_i, n_n=spec.n_n, n_o=spec.n_o, block_words=bw,
+            r_tile=1, moments=moments, interpret=True)
+        if bw > cgp_sim.default_block_words(W, n_o, moments):
+            with pytest.raises(ValueError, match="pass 2"):
+                call()
+            continue
+        out = [np.asarray(a) for a in call()]
+        _assert_exact(out, genomes, spec, gold, moments)
+        ints = [np.delete(out[0], FLOAT_SUMS, axis=1)] + out[1:]
+        if first is None:
+            first = ints, out[0][:, FLOAT_SUMS]
+            continue
+        for got, want in zip(ints, first[0]):
+            np.testing.assert_array_equal(got, want, err_msg=bw)
+        # float32 sums of 2^16 terms, in another order
+        np.testing.assert_allclose(out[0][:, FLOAT_SUMS], first[1],
+                                   rtol=1e-4)
 
 
 def test_block_follows_the_cube_and_the_carry():

@@ -142,78 +142,35 @@ def test_flash_attention_dtypes(dtype):
 
 
 # --------------------------------------------------------------------------
-# Tuning-table cache staleness (kernels/tune.py): the stat-token cache must
-# never serve a stale table after a rewrite, including same-mtime rewrites,
-# and must not re-parse a corrupt table on every resolve call
+# The kernel's one execution point: genome-major grid, the shape rule's
+# block, a pad of 8 compiled and 1 interpreted
 # --------------------------------------------------------------------------
 
-def _parse_counter(monkeypatch):
-    from repro.kernels import tune
-    calls = {"n": 0}
-    real = tune.json.load
+@pytest.mark.parametrize("moments", [False, True])
+@pytest.mark.parametrize("interpret", [True, False])
+def test_kernel_execution_point(interpret, moments, monkeypatch):
+    from repro.core.genome import Genome
+    from repro.kernels import cgp_sim
+    seen = []
+    real = cgp_sim.cgp_sim_metrics_batched
 
-    def counting(f):
-        calls["n"] += 1
-        return real(f)
+    def spy(*args, **kw):
+        seen.append(kw)
+        return real(*args, **kw)
 
-    monkeypatch.setattr(tune.json, "load", counting)
-    return calls
-
-
-def test_tune_table_cached_by_stat_token(tmp_path, monkeypatch):
-    from repro.kernels import tune
-    path = str(tmp_path / "table.json")
-    tune.save_entry(2, 3, "cpu", {"layout": "cube_major"}, path)
-    calls = _parse_counter(monkeypatch)
-    first = tune.load_table(path)
-    assert calls["n"] == 1
-    assert tune.load_table(path) == first  # unchanged file: cache hit
-    assert calls["n"] == 1
-    assert first["entries"][tune.table_key(2, 3, "cpu")]["layout"] \
-        == "cube_major"
-
-
-def test_tune_table_same_mtime_rewrite_detected(tmp_path):
-    """An atomic rewrite landing in the same mtime instant must still be
-    picked up: the rename gives the file a new inode, which the stat token
-    (mtime_ns, size, inode) sees even when mtime and size are unchanged."""
-    import os
-
-    from repro.kernels import tune
-    path = str(tmp_path / "table.json")
-    tune.save_entry(2, 3, "cpu", {"layout": "genome_major"}, path)
-    assert tune.resolve_layout(2, 3, "cpu", path) == "genome_major"
-    st = os.stat(path)
-    # atomic rename into place (fresh inode), then pin the mtime back
-    tune.save_entry(2, 3, "cpu", {"layout": "cube_major"}, path)
-    os.utime(path, ns=(st.st_atime_ns, st.st_mtime_ns))
-    now = os.stat(path)
-    assert now.st_mtime_ns == st.st_mtime_ns  # the hostile case: mtime lies
-    assert tune.resolve_layout(2, 3, "cpu", path) == "cube_major"
-
-
-def test_tune_table_corrupt_is_negative_cached(tmp_path, monkeypatch):
-    from repro.kernels import tune
-    path = str(tmp_path / "table.json")
-    with open(path, "w") as f:
-        f.write("{not json")
-    calls = _parse_counter(monkeypatch)
-    assert tune.load_table(path) == {}
-    assert tune.load_table(path) == {}  # not re-parsed per call
-    assert calls["n"] == 1
-    assert tune.resolve_variant(2, 3, "cpu", path) == tune.KernelVariant()
-    assert calls["n"] == 1
-    # a valid rewrite (new token) recovers without any cache poking
-    tune.save_entry(2, 3, "cpu", {"layout": "cube_major"}, path)
-    assert tune.resolve_layout(2, 3, "cpu", path) == "cube_major"
-
-
-def test_tune_save_entry_invalidates_cache(tmp_path):
-    from repro.kernels import tune
-    path = str(tmp_path / "table.json")
-    tune.save_entry(2, 3, "cpu", {"layout": "genome_major"}, path)
-    assert tune.resolve_layout(2, 3, "cpu", path) == "genome_major"
-    tune.save_entry(2, 8, "cpu", {"layout": "cube_major"}, path)
-    table = tune.load_table(path)
-    assert set(table["entries"]) == {tune.table_key(2, 3, "cpu"),
-                                     tune.table_key(2, 8, "cpu")}
+    monkeypatch.setattr(cgp_sim, "cgp_sim_metrics_batched", spy)
+    width, R = 2, 8
+    spec = CGPSpec(n_i=2 * width, n_o=2 * width, n_n=40)
+    W = max(1, (1 << spec.n_i) // 32)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    jax.eval_shape(
+        lambda n, o, p, g: ops.cgp_eval_batched(
+            Genome(n, o), spec, p, g, interpret=interpret, moments=moments),
+        i32(R, spec.n_n, 3), i32(R, spec.n_o), i32(spec.n_i, W),
+        i32(W * 32))
+    (kw,) = seen
+    assert kw["block_words"] is None  # the kernel's own shape rule
+    assert kw["r_tile"] == (1 if interpret else 8)
+    assert kw["moments"] is moments and kw["interpret"] is interpret
+    assert set(kw) == {"n_i", "n_n", "n_o", "gauss_sigma", "block_words",
+                       "r_tile", "moments", "interpret"}

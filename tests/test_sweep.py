@@ -160,17 +160,9 @@ def test_sweep_grid_order_matches_serial_loop():
 # Backend parity (fused pallas kernel path, ISSUE 2)
 # --------------------------------------------------------------------------
 
-def _test_layout() -> str:
-    """The CI kernel-differential matrix runs the pallas legs once per
-    evaluation-grid layout via REPRO_TEST_LAYOUT (default: auto)."""
-    env = os.environ.get("REPRO_TEST_LAYOUT")
-    return env if env in ("genome_major", "cube_major") else "auto"
-
-
 def _with_backend(backend: str):
     return dataclasses.replace(
-        PAR_CFG, evolve=dataclasses.replace(PAR_CFG.evolve, backend=backend,
-                                            layout=_test_layout()))
+        PAR_CFG, evolve=dataclasses.replace(PAR_CFG.evolve, backend=backend))
 
 
 PAR_CFG = SearchConfig(width=2, kind="add", n_n=40,

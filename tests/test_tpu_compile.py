@@ -2,9 +2,9 @@
 
 The TPU compiler ships with jaxlib and compiles for a topology that is
 described, not attached.  These tests catch what interpret mode cannot: a
-Pallas block shape or memory access Mosaic refuses, or a sharded program the
-partitioner rejects.  Nothing runs, so they say nothing about results or
-times (``chip_smoke.py`` runs the same programs on a chip).
+Pallas block shape or memory access Mosaic refuses.  Nothing runs, so they
+say nothing about results or times (``chip_smoke.py`` and the benchmark
+cells run the same programs on a chip).
 
 Only one process may load the TPU library at a time, so the topology is
 described inside a module fixture (never at import) and every compile stays
@@ -14,14 +14,12 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import sweep
 from repro.core.evolve import EvolveConfig
-from repro.core.genome import CGPSpec
+from repro.core.genome import CGPSpec, Genome
 from repro.kernels import cgp_sim, ops
 from repro.models import quant
 
@@ -72,12 +70,11 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("layout", ["genome_major", "cube_major"])
-def test_cgp_kernel_compiles_at_paper_shape(one_chip, layout):
+def test_cgp_kernel_compiles_at_paper_shape(one_chip):
     i32 = lambda *s: _shape(s, jnp.int32, one_chip)
     fn = jax.jit(lambda n, o, p, g: cgp_sim.cgp_sim_metrics_batched(
         n, o, p, g, n_i=N_I, n_n=N_N, n_o=N_O, block_words=512,
-        layout=layout, interpret=False))
+        interpret=False))
     compiled = fn.lower(i32(R, N_N, 3), i32(R, N_O), i32(N_I, W),
                         i32(W * 32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -93,7 +90,7 @@ def test_cgp_kernel_compiles_at_width_12(one_chip, moments):
     i32 = lambda *s: _shape(s, jnp.int32, one_chip)
     fn = jax.jit(lambda n, o, p, g: cgp_sim.cgp_sim_metrics_batched(
         n, o, p, g, n_i=n_i, n_n=n_n, n_o=n_o, block_words=512,
-        layout="genome_major", moments=moments, interpret=False))
+        moments=moments, interpret=False))
     compiled = fn.lower(i32(R, n_n, 3), i32(R, n_o), i32(n_i, w12),
                         i32(w12 * 32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -139,30 +136,25 @@ def test_characterize_chunk_compiles_at_width_12(one_chip,
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1 << 30
 
 
-def test_cube_sharded_sweep_chunk_compiles_on_four_chips(topo,
-                                                         compiled_kernels):
-    """The ``SweepConfig(model_axis="model")`` chunk program over a 2x2 host:
-    the cube splits four ways, every chip runs the fused kernel on its slice
-    and the partials all-reduce."""
-    mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4),
-                ("pod", "data", "model"))
-    spec = CGPSpec(n_i=N_I, n_o=N_O, n_n=N_N)
-    cfg = EvolveConfig(generations=2, lam=LAM, backend="pallas",
-                       layout="genome_major")
-    fn = sweep._sharded_chunk_fn(mesh, "model", spec, cfg)
-    rep = NamedSharding(mesh, P())
-    args = (_shape((N_N, 3), jnp.int32, rep),            # golden nodes
-            _shape((N_O,), jnp.int32, rep),              # golden outs
-            _shape((CHUNK, 7), jnp.float32, rep),        # thresholds
-            _shape((N_I, W), jnp.int32,
-                   NamedSharding(mesh, P(None, "model"))),  # input planes
-            _shape((W * 32,), jnp.int32,
-                   NamedSharding(mesh, P("model"))),     # golden values
-            _shape((), jnp.float32, rep),                # golden power
-            _shape((CHUNK, 2), jnp.uint32, rep))         # PRNG keys
-    text = fn.lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
-    assert "all-reduce" in text
+@pytest.mark.parametrize("n_i,n_o,n_n", [
+    pytest.param(16, 16, 400, id="mul8"),
+    pytest.param(16, 9, 100, id="add8"),
+    pytest.param(24, 24, 900, id="mul12"),
+])
+def test_sweep_chunk_compiles_at_cell_shape(one_chip, compiled_kernels, n_i,
+                                            n_o, n_n):
+    """The chunk program each benchmark cell runs: a chunk of 32 runs x
+    lambda 8 over the whole cube on one chip, the kernel compiled."""
+    spec = CGPSpec(n_i=n_i, n_o=n_o, n_n=n_n)
+    cfg = EvolveConfig(generations=2, lam=LAM, backend="pallas")
+    w = (1 << n_i) // 32
+    i32 = lambda *s: _shape(s, jnp.int32, one_chip)
+    compiled = sweep.evolve_chunk.lower(
+        spec, cfg, Genome(i32(n_n, 3), i32(n_o)),
+        _shape((CHUNK, 7), jnp.float32, one_chip), i32(n_i, w),
+        i32(w * 32), _shape((), jnp.float32, one_chip),
+        _shape((CHUNK, 2), jnp.uint32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_approx_matmul_compiles(one_chip):

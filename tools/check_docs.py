@@ -33,8 +33,8 @@ COMMAND_DOCS = ["README.md", "DESIGN.md", "ROADMAP.md"]
 SOURCE_DIRS = ["src", "benchmarks", "examples", "tests", "tools"]
 
 # top-level DESIGN.md sections that must exist (docstring references point
-# into these; §6 is the multi-host sweep surface, §7 the kernel-layout /
-# tuning surface, §8 the phenotype-dedup evaluation cache, §9 the sampled
+# into these; §6 is the multi-host sweep surface, §7 the kernel's
+# execution point, §8 the phenotype-dedup evaluation cache, §9 the sampled
 # evaluation mode, §10 the exact-verification escalation tier, §11 the
 # async commit pipeline + island migration, §12 the circuit-artifact
 # registry and the evolve → LUT → serve deployment path)
@@ -60,9 +60,9 @@ ENTRY_POINTS = [
 ]
 
 # flags that must exist in specific --help outputs even when no doc snippet
-# happens to pass them (the layout/tuning surface of DESIGN.md §7)
+# happens to pass them
 REQUIRED_FLAGS = {
-    ("-m", "repro.launch.evolve"): ["--layout", "--backend", "--dedup",
+    ("-m", "repro.launch.evolve"): ["--backend", "--dedup",
                                     "--dedup-cache-size", "--eval-mode",
                                     "--sample-size", "--input-dist",
                                     "--certify", "--certify-budget",
@@ -72,8 +72,7 @@ REQUIRED_FLAGS = {
     ("-m", "repro.launch.serve"): ["--approx-lut", "--summary-out"],
     ("-m", "repro.launch.export"): ["--results-dir", "--out", "--top-k",
                                     "--require-certified", "--verify"],
-    ("-m", "benchmarks.kernel_micro"): ["--layout", "--tune", "--json",
-                                        "--smoke"],
+    ("-m", "benchmarks.kernel_micro"): ["--json", "--smoke"],
     ("tools/check_bench.py",): ["--baseline", "--max-regression"],
 }
 
